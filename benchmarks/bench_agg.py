@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.timing import bench
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs import housing_mlp
 from repro.core import aggregation, naive, packing
 from repro.core.secure import secure_fedavg
@@ -603,6 +604,7 @@ def main(argv=None):
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="dump result rows as JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.sparse:
         if args.smoke:

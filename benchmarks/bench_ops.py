@@ -121,6 +121,9 @@ if __name__ == "__main__":
     ap.add_argument("--full", action="store_true",
                     help="paper-scale sweep: sizes x {10,25,50,100,200}")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.full:
         run(sizes=("100k", "1m", "10m"), learner_counts=(10, 25, 50, 100, 200))
     else:

@@ -420,6 +420,9 @@ def main(argv=None):
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="dump result rows as JSON")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.dispatch:
         if args.smoke:

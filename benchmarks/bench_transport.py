@@ -23,6 +23,7 @@ from repro.configs import housing_mlp
 from repro.core import Channel, naive, packing
 from repro.core.transport import TopkUploadCodec
 from repro.kernels.ops import QuantCodec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import mlp as mlp_model
 
 
@@ -243,6 +244,7 @@ def main(argv=None):
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="dump result rows as JSON")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.upload:
         rows = (run_upload(sizes=(2**16,), iters=2)

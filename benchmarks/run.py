@@ -21,12 +21,14 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks import bench_agg, bench_ops, bench_round, bench_transport, roofline_table
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true", help="paper-scale sweep (slow)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     results = {}
     print("# bench_agg (paper §4.2 parallel-aggregation claim)")

@@ -32,6 +32,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+# A TPU runs an f32 contraction as one bf16 pass unless asked otherwise; the
+# community model must be the f32 weighted mean, so every reduce asks.
+F32_EXACT = jax.lax.Precision.HIGHEST
+
 __all__ = [
     "fedavg",
     "weighted_average",
@@ -80,7 +84,7 @@ def weighted_average(stack: jax.Array, weights: jax.Array) -> jax.Array:
     perfectly regular reduction it can tile across all cores/chips.
     """
     w = _normalize(weights)
-    return jnp.einsum("n,np->p", w, stack.astype(jnp.float32))
+    return jnp.einsum("n,np->p", w, stack.astype(jnp.float32), precision=F32_EXACT)
 
 
 # FedAvg is a weighted average with example counts as weights.
@@ -113,7 +117,7 @@ def masked_weighted_average(
     m = jnp.asarray(mask, jnp.float32)
     w = masked_normalize(weights, m)
     rows = jnp.where(m[:, None] > 0, arena.astype(jnp.float32), 0.0)
-    return jnp.einsum("n,np->p", w, rows)
+    return jnp.einsum("n,np->p", w, rows, precision=F32_EXACT)
 
 
 # Masked FedAvg is a masked weighted average with example counts as weights.
@@ -142,7 +146,7 @@ def masked_staleness_average(
     w = staleness_weights(num_examples, stal, alpha)
     w = masked_normalize(w, m)
     rows = jnp.where(m[:, None] > 0, arena.astype(jnp.float32), 0.0)
-    return jnp.einsum("n,np->p", w, rows)
+    return jnp.einsum("n,np->p", w, rows, precision=F32_EXACT)
 
 
 def _dequant_rows(q: jax.Array, scales: jax.Array, group: int) -> jax.Array:
@@ -175,7 +179,7 @@ def masked_fedavg_q8(
     m = jnp.asarray(mask, jnp.float32)
     w = masked_normalize(weights, m)
     rows = jnp.where(m[:, None] > 0, _dequant_rows(q, scales, group), 0.0)
-    return jnp.einsum("n,np->p", w, rows)
+    return jnp.einsum("n,np->p", w, rows, precision=F32_EXACT)
 
 
 @functools.partial(jax.jit, static_argnames=("group",))
@@ -200,7 +204,7 @@ def masked_staleness_q8(
     stal = jnp.maximum(jnp.float32(current_version) - versions, 0.0)
     w = masked_normalize(staleness_weights(num_examples, stal, alpha), m)
     rows = jnp.where(m[:, None] > 0, _dequant_rows(q, scales, group), 0.0)
-    return jnp.einsum("n,np->p", w, rows)
+    return jnp.einsum("n,np->p", w, rows, precision=F32_EXACT)
 
 
 @functools.partial(jax.jit, static_argnames=("out_width",))
@@ -610,7 +614,7 @@ def hierarchical_fedavg(mesh: Mesh, pod_axis: str = "pod"):
         agg = jax.lax.psum(contrib, pod_axis) / jnp.maximum(wsum, 1e-12)
         return agg
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     return shard_map(
         agg,

@@ -33,6 +33,7 @@ posting ``UploadArrived`` out of order to pin that contract.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -291,6 +292,7 @@ class RoundEngine:
     ):
         self.controller = controller
         self._executor = ThreadPoolExecutor(max_workers=max_dispatch_workers)
+        self._device_slots: contextlib.AbstractContextManager | None = None
         self._events: queue.Queue = queue.Queue()
         self.event_log: collections.deque = collections.deque(maxlen=4096)
         self.journal = journal if journal is not None else EventJournal()
@@ -342,6 +344,29 @@ class RoundEngine:
         self.journal.record(event, **context)
 
     # -- dispatch -----------------------------------------------------------
+    def _learner_slots(self) -> contextlib.AbstractContextManager:
+        """The gate every learner task (recv + fit or evaluate) runs inside.
+
+        Learners train in this process, on JAX's default device.  An
+        accelerator runs one program at a time, and each running task holds
+        its own model copies there (received params, gradients, the packed
+        upload row), so a second task at once only overlaps host work while
+        adding one more footprint: eight fedlm-100m learners at once do not
+        fit beside their arena in a 16 GB v5e's HBM.  On an accelerator
+        tasks therefore run one at a time.  On the CPU backend programs run
+        side by side on the host's cores out of host RAM, and the dispatch
+        executor alone bounds them.  Decided at the first task, not at
+        construction, so building an engine initialises no backend.
+        """
+        if self._device_slots is None:
+            import jax
+
+            self._device_slots = (
+                contextlib.nullcontext() if jax.default_backend() == "cpu"
+                else threading.Semaphore(1)
+            )
+        return self._device_slots
+
     def _submit(self, lid: str, task: TrainTask, envelope: Any) -> None:
         """Fire-and-forget one task: recv + fit on a worker, post the arrival."""
         c = self.controller
@@ -350,11 +375,13 @@ class RoundEngine:
         # and its arrival takes the orphaned-upload path, instead of a
         # KeyError surfacing from the worker.
         learner = c._learners[lid]
+        slots = self._learner_slots()
 
         def work() -> None:
             try:
-                params = c.channel.recv(envelope)
-                update = learner.fit(params, task)
+                with slots:
+                    params = c.channel.recv(envelope)
+                    update = learner.fit(params, task)
                 self.post(UploadArrived(update=update))
             except BaseException as exc:  # surfaced on the loop thread
                 self.post(UploadArrived(update=None, error=exc))
@@ -458,14 +485,16 @@ class RoundEngine:
         c = self.controller
         t0 = time.perf_counter()
         broadcast = c._broadcast()
+        slots = self._learner_slots()
         futures = []
         # Members that deregistered mid-round are skipped, not fatal.
         for lid in [x for x in state.cohort if x in c._learners]:
             envelope = broadcast.to({"eval": True})
 
             def run(lid=lid, envelope=envelope) -> EvalReport:
-                params = c.channel.recv(envelope)
-                return c._learners[lid].evaluate(params, c.round_id)
+                with slots:
+                    params = c.channel.recv(envelope)
+                    return c._learners[lid].evaluate(params, c.round_id)
 
             futures.append(self._executor.submit(run))
         state.timings.eval_dispatch_s = time.perf_counter() - t0

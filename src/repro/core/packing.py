@@ -142,14 +142,17 @@ def num_params(params: Any) -> int:
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnames=("dtype", "pad_to"))
 def pack_numeric(
     params: Any, dtype: jnp.dtype = jnp.float32, pad_to: int | None = None
 ) -> jax.Array:
     """Flatten a pytree into one 1-D buffer in the accumulation dtype.
 
-    jit-compatible; under ``pjit`` the output buffer inherits a sharding over
-    the flattened dimension, so the downstream aggregation reduce is local to
-    every device (no collectives) — see ``core/aggregation.py``.
+    One jitted program per tree structure, so the buffer is written once,
+    with no per-leaf or partial-concatenation copies; under ``pjit`` the
+    output buffer inherits a sharding over the flattened dimension, so the
+    downstream aggregation reduce is local to every device (no collectives)
+    — see ``core/aggregation.py``.
 
     ``pad_to`` zero-pads the buffer length up to the next multiple — the
     VPU-lane alignment the arena store (``core/store.ArenaStore``) and the
@@ -157,15 +160,14 @@ def pack_numeric(
     per-call padding downstream.  ``unpack_numeric`` is oblivious: the
     manifest records the logical offsets and the zero tail never escapes.
     """
-    leaves = jax.tree_util.tree_leaves(params)
-    if not leaves:
-        buf = jnp.zeros((0,), dtype=dtype)
-    else:
-        flat = [jnp.ravel(jnp.asarray(l)).astype(dtype) for l in leaves]
-        buf = jnp.concatenate(flat, axis=0)
-    if pad_to is not None and buf.shape[0] % pad_to:
-        buf = jnp.pad(buf, (0, round_up(buf.shape[0], pad_to) - buf.shape[0]))
-    return buf
+    flat = [jnp.ravel(jnp.asarray(l)).astype(dtype)
+            for l in jax.tree_util.tree_leaves(params)]
+    n = sum(int(f.shape[0]) for f in flat)
+    if pad_to is not None and n % pad_to:
+        flat.append(jnp.zeros((round_up(n, pad_to) - n,), dtype))
+    if not flat:
+        return jnp.zeros((0,), dtype=dtype)
+    return jnp.concatenate(flat, axis=0)
 
 
 def unpack_numeric(buffer: jax.Array, manifest: Manifest) -> Any:
@@ -254,23 +256,24 @@ def pack_row_bytes(buffer: Any, dtype: Any = jnp.float32) -> np.ndarray:
     return host.reshape(-1).astype(dt, copy=True).view(np.uint8)
 
 
-@functools.partial(jax.jit, static_argnames=("num_elements", "dtype"))
-def _bitcast_row_device(wire: jax.Array, num_elements: int, dtype: str) -> jax.Array:
-    """Device-side inverse of :func:`pack_row_bytes` (compiled per layout)."""
-    dt = jnp.dtype(dtype)
-    if dt.itemsize == 1:
-        row = jax.lax.bitcast_convert_type(wire, dt)
-    else:
-        row = jax.lax.bitcast_convert_type(wire.reshape(num_elements, dt.itemsize), dt)
-    return row.reshape(num_elements)
+def wire_view(wire: np.ndarray, start: int, count: int, dtype: Any) -> np.ndarray:
+    """``count`` elements of ``dtype`` at byte ``start`` of a wire buffer.
+
+    A zero-copy host view.  Wire bytes are reinterpreted here, on the host,
+    and never by a device bitcast: a TPU lays out the ``(count, itemsize)``
+    bytes such a bitcast needs with the last axis padded to 128 lanes, so
+    decoding a 74M-element f32 row on the device asks for 38 GB.
+    """
+    dt = np.dtype(jnp.dtype(dtype))
+    flat = np.ascontiguousarray(wire).reshape(-1)
+    return flat[start : start + count * dt.itemsize].view(dt)
 
 
 def unpack_row_bytes(wire: np.ndarray, num_elements: int, dtype: Any = "float32") -> jax.Array:
-    """Inverse of :func:`pack_row_bytes`: **one** ``device_put`` of the wire
-    bytes, then a jitted device-side bitcast back to the ``(P,)`` row.
+    """Inverse of :func:`pack_row_bytes`: a host view of the wire bytes as
+    the ``(P,)`` row, then **one** ``device_put``.
 
-    Mirrors :func:`unpack_bytes`' one-transfer design on the upload direction:
-    a controller ingesting N uploads per round pays N single O(P) transfers
+    A controller ingesting N uploads per round pays N single O(P) transfers
     and zero host-side numeric work, regardless of model depth.
     """
     dt = jnp.dtype(dtype)
@@ -280,41 +283,24 @@ def unpack_row_bytes(wire: np.ndarray, num_elements: int, dtype: Any = "float32"
             f"{int(num_elements) * dt.itemsize} for {num_elements} "
             f"{dt.name} elements"
         )
-    dev = jnp.asarray(np.ascontiguousarray(wire))
-    return _bitcast_row_device(dev, int(num_elements), str(dt))
-
-
-@functools.partial(jax.jit, static_argnames="manifest")
-def _unpack_bytes_device(buffer: jax.Array, manifest: Manifest) -> Any:
-    """Device-side wire decode: slice + bitcast every tensor out of one
-    resident ``uint8`` buffer (compiled once per manifest, cached)."""
-    leaves = []
-    cursor = 0
-    for spec in manifest.specs:
-        dt = jnp.dtype(spec.dtype)
-        seg = jax.lax.slice(buffer, (cursor,), (cursor + spec.nbytes,))
-        if dt == jnp.dtype(bool):
-            leaf = seg.astype(bool)  # XLA cannot bitcast to pred
-        elif dt.itemsize == 1:
-            leaf = jax.lax.bitcast_convert_type(seg, dt)
-        else:
-            leaf = jax.lax.bitcast_convert_type(seg.reshape(spec.size, dt.itemsize), dt)
-        leaves.append(leaf.reshape(spec.shape))
-        cursor += spec.nbytes
-    return jax.tree_util.tree_unflatten(manifest.treedef, leaves)
+    return jax.device_put(wire_view(wire, 0, int(num_elements), dt))
 
 
 def unpack_bytes(buffer: np.ndarray, manifest: Manifest) -> Any:
-    """Inverse of :func:`pack_bytes`: **one** ``device_put`` of the whole wire
-    buffer, then device-side slices + bitcasts per tensor.
+    """Inverse of :func:`pack_bytes`: host views of every tensor, then
+    **one** batched ``device_put`` of the whole tree.
 
-    The legacy implementation transferred one tensor at a time (one host→
-    device copy per leaf — hundreds for a deep model); this path moves the
-    buffer once and reconstructs every tensor on device through a jitted
-    program cached per manifest, so a receiver's deserialization cost is a
-    single O(P) transfer regardless of how many tensors the model has.
+    Each leaf is a zero-copy view into the wire buffer (:func:`wire_view`),
+    so a receiver's deserialization cost is the O(P) transfer itself, with
+    no per-leaf host copy and no decode program to compile.
     """
-    if not manifest.specs:
-        return jax.tree_util.tree_unflatten(manifest.treedef, [])
-    dev = jnp.asarray(np.ascontiguousarray(buffer))
-    return _unpack_bytes_device(dev, manifest)
+    leaves = []
+    cursor = 0
+    for spec in manifest.specs:
+        if jnp.dtype(spec.dtype) == jnp.dtype(bool):
+            leaf = wire_view(buffer, cursor, spec.size, np.uint8).astype(bool)
+        else:
+            leaf = wire_view(buffer, cursor, spec.size, spec.dtype)
+        leaves.append(leaf.reshape(spec.shape))
+        cursor += spec.nbytes
+    return jax.tree_util.tree_unflatten(manifest.treedef, jax.device_put(leaves))

@@ -244,7 +244,7 @@ def _make_sharded_writer(mesh, axes):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     def _write(arena, row, buf):
         return jax.lax.dynamic_update_slice(arena, buf[None, :], (row, 0))
@@ -322,10 +322,11 @@ class ArenaStore:
         self.lock = threading.RLock()
         self.mesh = mesh
         if mesh is not None:
+            from repro.core.aggregation import arena_axes
             from repro.models.sharding import arena_specs
 
             buf_s, row_s, repl_s = arena_specs(mesh, axes)
-            self.axes = row_s.spec[0]
+            self.axes = arena_axes(mesh, axes)
             self.buffer_sharding, self.row_sharding = buf_s, row_s
             self.n_shards = int(
                 np.prod([mesh.shape[a] for a in self.axes], dtype=np.int64)

@@ -25,8 +25,9 @@ The channel is **full duplex** — both wire directions are measured:
   upload codec (``raw`` passthrough — 4 bytes/param; ``int8`` blockwise
   quantization via ``kernels/quantize`` — ~3.9x fewer wire bytes) into an
   :class:`UploadEnvelope`, with per-send byte/time accounting; the controller
-  decodes it back to a device-resident row with one ``device_put`` plus a
-  jitted bitcast/dequant program, ready for a straight arena row write.
+  decodes it back to a device-resident row from host views of the wire
+  bytes (``packing.wire_view``): one ``device_put`` plus, for ``int8``, a
+  jitted dequant program, ready for a straight arena row write.
   Uplink is the dominant wire direction (N uploads vs 1 broadcast per round),
   so this is where the codec pays off.
 
@@ -167,97 +168,70 @@ class RawUploadCodec:
     def decode_with_norm(
         self, payload: np.ndarray, num_elements: int
     ) -> tuple[jax.Array, jax.Array]:
-        """Decode + L2 norm in one jitted device program (no host sync).
+        """Decode, then the L2 norm as the upload's one device program.
 
         The admission-screen fast path: the norm comes back as a device
-        scalar enqueued behind the decode, so the controller's only host
+        scalar enqueued behind the transfer, so the controller's only host
         sync per upload is reading the already-materialized float.
         """
-        if int(np.size(payload)) != 4 * int(num_elements):
-            raise ValueError(
-                f"row payload holds {int(np.size(payload))} bytes, expected "
-                f"{4 * int(num_elements)} for {num_elements} float32 elements"
-            )
-        dev = jnp.asarray(np.ascontiguousarray(payload))
-        return _raw_decode_norm(dev, int(num_elements))
-
-
-@functools.partial(jax.jit, static_argnames=("num_elements",))
-def _raw_decode_norm(wire: jax.Array, num_elements: int):
-    """One jitted program: bitcast the raw f32 wire bytes + its L2 norm."""
-    row = jax.lax.bitcast_convert_type(
-        wire.reshape(num_elements, 4), jnp.float32
-    ).reshape(num_elements)
-    return row, jnp.linalg.norm(row)
+        row = self.decode(payload, num_elements)
+        return row, _row_norm(row)
 
 
 @jax.jit
 def _row_norm(row: jax.Array) -> jax.Array:
-    """Device-side L2 norm of a decoded row (fallback for custom codecs)."""
+    """Device-side L2 norm of a decoded row."""
     return jnp.linalg.norm(row.astype(jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("n_q", "n_scales", "n_groups"))
-def _split_quant_wire(wire: jax.Array, n_q: int, n_scales: int, n_groups: int):
-    """Device-side split of one int8 upload payload into (q int8, scales f32).
+def _put_quant_wire(payload: np.ndarray, n_q: int, n_scales: int, group: int):
+    """One int8 upload payload as device ``(q int8 (n_q,), scales f32)``.
 
-    Compiled once per wire layout and cached — together with the jitted
-    ``kernels/ops.dequantize`` this makes the controller's int8 ingest a
-    single ``device_put`` plus device-only bitcasts and the dequant kernel,
-    mirroring the downlink's one-transfer ``unpack_bytes`` design.
-
-    The wire carries only the ``n_scales = ceil(n/group)`` informative
-    scales (``kernels/quantize.wire_layout`` trims pure-padding groups); the
-    remaining ``n_groups - n_scales`` trailing groups are re-synthesized
-    here as exactly 1.0 — the quantize kernel's zero-amax fallback — so the
-    round-trip stays bit-identical to an untrimmed wire.
+    Split on the host by zero-copy views (``packing.wire_view``), then one
+    batched ``device_put``.  The wire carries only the
+    ``n_scales = ceil(n/group)`` informative scales
+    (``kernels/quantize.wire_layout`` trims pure-padding groups); the
+    remaining trailing groups are re-synthesized here as exactly 1.0 — the
+    quantize kernel's zero-amax fallback — so the round-trip stays
+    bit-identical to an untrimmed wire.
     """
-    q = jax.lax.bitcast_convert_type(jax.lax.slice(wire, (0,), (n_q,)), jnp.int8)
-    sb = jax.lax.slice(wire, (n_q,), (n_q + 4 * n_scales,))
-    scales = jax.lax.bitcast_convert_type(sb.reshape(n_scales, 4), jnp.float32)
-    scales = scales.reshape(n_scales)
-    if n_groups > n_scales:
-        pad = jnp.ones((n_groups - n_scales,), jnp.float32)
-        scales = jnp.concatenate([scales, pad])
-    return q, scales
+    q = packing.wire_view(payload, 0, n_q, np.int8)
+    scales = packing.wire_view(payload, n_q, n_scales, np.float32)
+    n_pad = n_q // group - n_scales
+    if n_pad > 0:
+        scales = np.concatenate([scales, np.ones((n_pad,), np.float32)])
+    return jax.device_put((q, scales))
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_q", "n_scales", "num_elements", "group", "block_rows"),
-)
-def _int8_decode_norm(wire, n_q, n_scales, num_elements, group, block_rows):
-    """One jitted program: split + re-pad + dequantize + L2 norm.
+@functools.partial(jax.jit, static_argnames=("num_elements", "group", "block_rows"))
+def _int8_decode_norm(q, scales, num_elements, group, block_rows):
+    """One jitted program: dequantize + L2 norm.
 
-    The int8 statement of :func:`_raw_decode_norm`: the whole decode and the
-    admission norm compile into a single cached executable per wire layout,
-    so ingest enqueues one device program and never blocks.
+    The decode and the admission norm compile into a single cached
+    executable per wire layout, so ingest enqueues one device program and
+    never blocks.
     """
     from repro.kernels import ops as kops
     from repro.kernels import quantize as quant
 
-    q, scales = _split_quant_wire(wire, n_q, n_scales, n_q // group)
     row = quant.dequantize_pallas(
-        q, scales, group, block_rows, interpret=kops.INTERPRET
+        q, scales, group, block_rows, interpret=kops.interpret_mode()
     )[:num_elements]
     return row, jnp.linalg.norm(row)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n_q", "n_scales", "out_params", "group")
-)
-def _decode_quant_resident(wire, n_q, n_scales, out_params, group):
+@functools.partial(jax.jit, static_argnames=("out_params", "group"))
+def _decode_quant_resident(q, scales, out_params, group):
     """Land one int8 upload in quantized form: (q int8, scales f32, norm).
 
-    The quantized-resident arena's ingest program: split the wire, re-pad
-    the trimmed scales, slice to the arena row width — **no f32 (P,) row is
-    ever materialized**.  The admission norm is computed from the quantized
+    The quantized-resident arena's ingest program: slice the wire's values
+    and scales to the arena row width — **no f32 (P,) row is ever
+    materialized**.  The admission norm is computed from the quantized
     form directly, ``sqrt(Σ_g scale_g² · Σ_i q_{g,i}²)``, which equals the
     L2 norm of the dequantized row exactly (dequantization is a per-group
     scalar multiply), so screening decisions match the f32 path bit-for-bit
     up to f32 summation order.
     """
-    q, scales = _split_quant_wire(wire, n_q, n_scales, n_q // group)
     q = jax.lax.slice(q, (0,), (out_params,))
     scales = jax.lax.slice(scales, (0,), (out_params // group,))
     qf = q.astype(jnp.float32).reshape(out_params // group, group)
@@ -276,8 +250,8 @@ class Int8UploadCodec:
     zero rows and larger buffers pad at most ~6.25% of their rows, so the
     compression ratio is ≈3.94x at block-aligned sizes and never drops below
     ~3.6x once P reaches one group — there is no size band where the pad to
-    the next whole tile halves the saving.  Decode is one ``device_put`` of the
-    payload, a jitted bitcast split, and the Pallas dequant kernel — the
+    the next whole tile halves the saving.  Decode is a host split of the
+    payload, one ``device_put`` and the Pallas dequant kernel — the
     decoded f32 row is ready for a straight arena row write with zero
     host-side numeric work.  Lossy to the int8 step (~0.4% relative); use
     ``raw`` where bit-identity matters.
@@ -346,17 +320,7 @@ class Int8UploadCodec:
 
     def decode(self, payload: np.ndarray, num_elements: int) -> jax.Array:
         """Dequantize an int8 payload back to the f32 ``(P,)`` row."""
-        from repro.kernels import ops, quantize as quant
-
-        n_q, n_scales = self._checked_layout(payload, num_elements)
-        dev = jnp.asarray(np.ascontiguousarray(payload))
-        q, scales = _split_quant_wire(dev, n_q, n_scales, n_q // self.group)
-        return ops.dequantize(
-            q, scales, num_elements, group=self.group,
-            block_rows=quant.effective_block_rows(
-                num_elements, self.group, self.block_rows
-            ),
-        )
+        return self.decode_with_norm(payload, num_elements)[0]
 
     def decode_with_norm(
         self, payload: np.ndarray, num_elements: int
@@ -369,9 +333,9 @@ class Int8UploadCodec:
         from repro.kernels import quantize as quant
 
         n_q, n_scales = self._checked_layout(payload, num_elements)
-        dev = jnp.asarray(np.ascontiguousarray(payload))
+        q, scales = _put_quant_wire(payload, n_q, n_scales, self.group)
         return _int8_decode_norm(
-            dev, n_q, n_scales, int(num_elements), self.group,
+            q, scales, int(num_elements), self.group,
             quant.effective_block_rows(
                 int(num_elements), self.group, self.block_rows
             ),
@@ -395,60 +359,49 @@ class Int8UploadCodec:
                 f"group={self.group} and <= the payload's {n_q} padded "
                 "elements"
             )
-        dev = jnp.asarray(np.ascontiguousarray(payload))
-        return _decode_quant_resident(dev, n_q, n_scales, out_params, self.group)
+        q, scales = _put_quant_wire(payload, n_q, n_scales, self.group)
+        return _decode_quant_resident(q, scales, out_params, self.group)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("k_eff", "n_scales", "group", "value_dtype")
-)
-def _split_topk_wire(wire, k_eff, n_scales, group, value_dtype):
-    """Device-side split of one topk payload into (idx int32, val f32, norm).
+def _split_topk_wire(payload, k_eff, n_scales, group, value_dtype):
+    """Split one topk payload into device (idx int32, val f32, norm).
 
-    One cached executable per wire layout: bitcast the int32 index block,
-    bitcast (f32 values) or bitcast + dequantize (int8-grouped values) the
-    value block, and fuse the sparse L2 norm.  Top-k indices are unique
-    within one upload, so ``‖val‖₂`` **is** the L2 norm of the densified
-    row — the admission screen reads the same scalar the dense codecs
-    produce, without ever materializing the ``(P,)`` row.
+    The index block and the value block (f32, or int8 values plus their
+    group scales) are host views of the wire (``packing.wire_view``) moved
+    by one batched ``device_put``; one cached executable per wire layout
+    dequantizes the values and computes the sparse L2 norm.  Top-k indices
+    are unique within one upload, so ``‖val‖₂`` **is** the L2 norm of the
+    densified row — the admission screen reads the same scalar the dense
+    codecs produce, without ever materializing the ``(P,)`` row.
     """
+    idx = packing.wire_view(payload, 0, k_eff, np.int32)
+    if value_dtype == "f32":
+        vals = (packing.wire_view(payload, 4 * k_eff, k_eff, np.float32),)
+    else:
+        vals = (packing.wire_view(payload, 4 * k_eff, k_eff, np.int8),
+                packing.wire_view(payload, 5 * k_eff, n_scales, np.float32))
+    idx, vals = jax.device_put((idx, vals))
+    return (idx, *_topk_values_norm(vals, group))
+
+
+@functools.partial(jax.jit, static_argnames=("group",))
+def _topk_values_norm(vals, group):
+    """f32 values (dequantized when int8-grouped) and their L2 norm."""
     from repro.kernels import topk as topk_kernels
 
-    idx = jax.lax.bitcast_convert_type(
-        jax.lax.slice(wire, (0,), (4 * k_eff,)).reshape(k_eff, 4), jnp.int32
-    ).reshape(k_eff)
-    if value_dtype == "f32":
-        vb = jax.lax.slice(wire, (4 * k_eff,), (8 * k_eff,))
-        val = jax.lax.bitcast_convert_type(
-            vb.reshape(k_eff, 4), jnp.float32
-        ).reshape(k_eff)
-    else:
-        q = jax.lax.bitcast_convert_type(
-            jax.lax.slice(wire, (4 * k_eff,), (5 * k_eff,)), jnp.int8
-        )
-        sb = jax.lax.slice(wire, (5 * k_eff,), (5 * k_eff + 4 * n_scales,))
-        scales = jax.lax.bitcast_convert_type(
-            sb.reshape(n_scales, 4), jnp.float32
-        ).reshape(n_scales)
-        val = topk_kernels.dequantize_values(q, scales, group)
-    return idx, val, jnp.linalg.norm(val)
+    val = vals[0] if len(vals) == 1 else topk_kernels.dequantize_values(*vals, group)
+    return val, jnp.linalg.norm(val)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("k_eff", "n_scales", "group", "value_dtype",
-                     "num_elements"),
-)
-def _topk_decode_norm(wire, k_eff, n_scales, group, value_dtype, num_elements):
-    """One jitted program: split + densify into a ``(P,)`` delta row + norm.
+@functools.partial(jax.jit, static_argnames=("num_elements",))
+def _densify(idx, val, num_elements):
+    """Scatter a sparse upload into a dense ``(P,)`` delta row.
 
     The densify fallback for consumers that need a dense row (the
     ``densify`` sparse mode, the stack store, median/trimmed_mean);
     the direct sparse path never calls this.
     """
-    idx, val, norm = _split_topk_wire(wire, k_eff, n_scales, group, value_dtype)
-    row = jnp.zeros((num_elements,), jnp.float32).at[idx].add(val)
-    return row, norm
+    return jnp.zeros((num_elements,), jnp.float32).at[idx].add(val)
 
 
 class TopkUploadCodec:
@@ -548,9 +501,8 @@ class TopkUploadCodec:
         see, so ``residual -= sent`` carries the quantization error too.
         """
         k_eff, n_scales = self._checked_layout(payload, num_elements)
-        dev = jnp.asarray(np.ascontiguousarray(payload))
         idx, val, _ = _split_topk_wire(
-            dev, k_eff, n_scales, self.group, self.value_dtype
+            payload, k_eff, n_scales, self.group, self.value_dtype
         )
         return idx, val
 
@@ -561,13 +513,9 @@ class TopkUploadCodec:
     def decode_with_norm(
         self, payload: np.ndarray, num_elements: int
     ) -> tuple[jax.Array, jax.Array]:
-        """Densify + L2 norm in one jitted device program (no host sync)."""
-        k_eff, n_scales = self._checked_layout(payload, num_elements)
-        dev = jnp.asarray(np.ascontiguousarray(payload))
-        return _topk_decode_norm(
-            dev, k_eff, n_scales, self.group, self.value_dtype,
-            int(num_elements),
-        )
+        """Densify + L2 norm as device programs (no host sync)."""
+        idx, val, norm = self.decode_sparse(payload, num_elements)
+        return _densify(idx, val, int(num_elements)), norm
 
     def decode_sparse(
         self, payload: np.ndarray, num_elements: int
@@ -579,9 +527,8 @@ class TopkUploadCodec:
         row's norm, indices being unique) as an unread device scalar.
         """
         k_eff, n_scales = self._checked_layout(payload, num_elements)
-        dev = jnp.asarray(np.ascontiguousarray(payload))
         return _split_topk_wire(
-            dev, k_eff, n_scales, self.group, self.value_dtype
+            payload, k_eff, n_scales, self.group, self.value_dtype
         )
 
 
@@ -923,15 +870,15 @@ class Channel:
     ) -> jax.Array | tuple[jax.Array, jax.Array]:
         """Controller half of the uplink: decode wire bytes to a device row.
 
-        One ``device_put`` of the payload plus a jitted decode program cached
-        per wire layout (bitcast for ``raw``, bitcast split + Pallas dequant
-        for ``int8``) — the returned f32 ``(P,)`` row feeds a straight arena
-        row write with zero host-side numeric work.
+        One ``device_put`` of host views of the payload, plus for ``int8`` a
+        jitted Pallas dequant program cached per wire layout — the returned
+        f32 ``(P,)`` row feeds a straight arena row write with zero
+        host-side numeric work.
 
         With ``with_norm=True`` returns ``(row, norm)`` where ``norm`` is the
         row's L2 norm as a **device scalar** fused into (or enqueued behind)
         the decode program — the admission screen's non-blocking readback.
-        Registry codecs fuse it into the decode executable; a custom codec
+        Registry codecs compute it in their one decode program; a custom codec
         without ``decode_with_norm`` pays one extra enqueued jit, still with
         zero host syncs.
         """

@@ -10,9 +10,10 @@ the aggregate.
 Arithmetic intensity is ~1 FLOP per 2 bytes for f32 inputs (2·N·P FLOPs over
 N·P·4 bytes), so the kernel is HBM-bandwidth-bound; the tiling's only job is
 to keep the block resident and the lanes full (block_p a multiple of
-8·128 = 1024 f32 lanes).  Validated in interpret mode against
-``ref.fedavg_ref`` (CPU has no real TPU here); the jit wrapper lives in
-``ops.py``.
+8·128 = 1024 f32 lanes) inside the compiler's scoped VMEM.  Numerics are
+checked in interpret mode against ``ref.fedavg_ref``, and the TPU lowering
+by compiling for a described v5e (``tests/test_tpu_compile.py``); the jit
+wrapper lives in ``ops.py``.
 """
 
 from __future__ import annotations
@@ -35,23 +36,25 @@ __all__ = [
 # 8 sublanes x 128 lanes x 16 vregs worth of f32 per tile step
 DEFAULT_BLOCK_P = 16384
 
-# v5e VMEM is ~128 MiB/core; leave headroom for double-buffering (the Mosaic
-# pipeliner keeps 2 in-flight copies of every input tile) and the output tile.
-VMEM_BUDGET_BYTES = 64 * 1024 * 1024
+# Mosaic lets a kernel use 16 MiB of scoped VMEM on v5e unless the call
+# raises ``vmem_limit_bytes`` (the core has 128 MiB in all).  Tiles are sized
+# to a budget inside that default, so no kernel passes the override; an
+# (8, 262144) f32 tile (8 MiB, 16 MiB double-buffered) is already refused.
+VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 
 def choose_block_p(n_learners: int, dtype_bytes: int = 4,
                    budget: int = VMEM_BUDGET_BYTES) -> int:
     """Largest lane-aligned block_p whose working set fits VMEM.
 
-    Working set per grid step ≈ 2·(N·block_p·dtype_bytes)  (double-buffered
-    stack tile) + block_p·4 (f32 out) + N·4 (weights).  Solving for block_p
-    and rounding down to a multiple of 1024 (8 sublanes × 128 lanes) keeps the
-    VPU lanes full while never spilling:  N=8 → 1.0M elements; N=200 → 40k.
-    The sweep in EXPERIMENTS.md §Perf confirms HBM-bound behaviour is flat
-    across valid block sizes — the only failure mode is exceeding VMEM.
+    Working set per grid step ≈ 2·N·block_p·dtype_bytes (the pipeliner
+    double-buffers the input tile) + N·block_p·4 (the kernel's masked f32
+    copy of the tile) + 2·block_p·4 (double-buffered f32 out) + N·4
+    (weights).  Solving for block_p and rounding down to a multiple of 1024
+    (8 sublanes × 128 lanes) keeps the VPU lanes full while staying inside
+    the compiler's scoped VMEM: N=8 → 120,832 elements (f32); N=200 → 4,096.
     """
-    per_elem = 2 * n_learners * dtype_bytes + 4
+    per_elem = 2 * n_learners * dtype_bytes + 4 * n_learners + 8
     raw = (budget - 4 * n_learners) // per_elem
     aligned = max(1024, (raw // 1024) * 1024)
     return int(min(aligned, 1 << 20))
@@ -118,6 +121,7 @@ def _fedavg_kernel(w_ref, stack_ref, out_ref):
     acc = jax.lax.dot_general(
         w[None, :], block,
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # not one bf16 pass
         preferred_element_type=jnp.float32,
     )  # (1, BP)
     out_ref[...] = acc
@@ -173,6 +177,7 @@ def _masked_fedavg_kernel(w_ref, mask_ref, arena_ref, out_ref):
     acc = jax.lax.dot_general(
         w[None, :], block,
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # not one bf16 pass
         preferred_element_type=jnp.float32,
     )  # (1, BP)
     out_ref[...] = acc

@@ -15,9 +15,11 @@ instead of ~9·N·P.
 Tiling follows ``kernels/fedavg.py``: ``block_p`` is VMEM-budgeted, lane-
 aligned, a multiple of the quant group (so every tile holds whole groups)
 and — on the arena hot path — an exact divisor of the padded row width, so
-nothing is ever re-padded.  Validated in interpret mode against the f64
-``ref.masked_fedavg_q8_ref`` oracle; the jit wrapper and the column-sharded
-``shard_map`` variant (zero collectives) live in ``ops.py``.
+nothing is ever re-padded.  Numerics are checked in interpret mode against
+the f64 ``ref.masked_fedavg_q8_ref`` oracle, and the TPU lowering by
+compiling for a described v5e (``tests/test_tpu_compile.py``); the jit
+wrapper and the column-sharded ``shard_map`` variant (zero collectives)
+live in ``ops.py``.
 """
 
 from __future__ import annotations
@@ -56,15 +58,17 @@ def choose_block_p_q8(
 ) -> int:
     """Largest aligned block_p whose fused working set fits VMEM.
 
-    Working set per grid step ≈ 2·N·block_p (double-buffered int8 tile)
-    + 2·N·(block_p/group)·4 (scale tiles) + N·block_p·4 (the in-kernel f32
-    dequantized block) + block_p·4 (out) + 2·N·4 (weights + mask).  Solving
-    for block_p and rounding down to a multiple of lcm(1024, group) keeps
-    the lanes full and every tile a whole number of groups.  The fused
-    working set per element (~6·N bytes) is smaller than the f32 kernel's
-    (~8·N), so the quantized arena sustains *larger* tiles at equal VMEM.
+    Working set per grid step ≈ 2·N₃₂·block_p (double-buffered int8 tile,
+    its rows padded to the 32-row int8 tiling) + 2·N₈·block_p·4 (the
+    in-kernel f32 dequantized block and its per-group view, rows padded to
+    8) + 2·N·(block_p/group)·4 (scale tiles) + 2·block_p·4
+    (double-buffered out).  Solving for block_p and rounding down to a
+    multiple of lcm(1024, group) keeps the lanes full and every tile a
+    whole number of groups.
     """
-    per_elem = 2 * n_learners + 4 * n_learners + (8 * n_learners) // group + 4
+    n32 = -(-n_learners // 32) * 32
+    n8 = -(-n_learners // 8) * 8
+    per_elem = 2 * n32 + 8 * n8 + (8 * n_learners) // group + 8
     raw = (budget - 8 * n_learners) // per_elem
     align = _lcm(_LANE_MULTIPLE, group)
     aligned = max(align, (raw // align) * align)
@@ -142,6 +146,7 @@ def _masked_fedavg_q8_kernel(w_ref, mask_ref, q_ref, s_ref, out_ref, *,
     acc = jax.lax.dot_general(
         w[None, :], block,
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # not one bf16 pass
         preferred_element_type=jnp.float32,
     )  # (1, BP)
     out_ref[...] = acc
@@ -184,19 +189,24 @@ def masked_fedavg_q8_pallas(
     m = mask.astype(jnp.float32)
     w = masked_normalize(weights, m)
 
-    grid = (p // block_p,)
-    sblock = block_p // group
+    nblk, sblock = p // block_p, block_p // group
+    # A (N, block_p/group) window of the (N, P/group) scales breaks the TPU
+    # (8, 128) tiling rule unless block_p/group is a multiple of 128.  Laid
+    # out as (P/block_p, N, block_p/group), each grid step's scale tile is a
+    # whole trailing (N, block_p/group) slab, legal for every block_p.  The
+    # relayout copies the scales once per call (4/group of the int8 bytes).
+    scales_t = scales.reshape(n, nblk, sblock).transpose(1, 0, 2)
     out = pl.pallas_call(
         functools.partial(_masked_fedavg_q8_kernel, group=group),
-        grid=grid,
+        grid=(nblk,),
         in_specs=[
             pl.BlockSpec((n, 1), lambda i: (0, 0)),
             pl.BlockSpec((n, 1), lambda i: (0, 0)),
             pl.BlockSpec((n, block_p), lambda i: (0, i)),
-            pl.BlockSpec((n, sblock), lambda i: (0, i)),
+            pl.BlockSpec((None, n, sblock), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_p), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, p), jnp.float32),
         interpret=interpret,
-    )(w[:, None], m[:, None], q, scales)
+    )(w[:, None], m[:, None], q, scales_t)
     return out[0]

@@ -1,8 +1,9 @@
 """jit'd public wrappers around the Pallas kernels (padding + dispatch).
 
-``INTERPRET`` flips the kernels into interpret mode — required on CPU, where
-the kernel body executes in Python for correctness validation; on a real TPU
-it is False and the kernels compile to Mosaic.
+:func:`interpret_mode` decides, when a wrapper is traced, whether the kernels
+compile to Mosaic (a TPU backend) or run in the Pallas interpreter (any other
+backend: the CPU that the tests use).  Importing this module initializes no
+backend.
 """
 
 from __future__ import annotations
@@ -17,15 +18,22 @@ from repro.kernels import fused_agg as _fused
 from repro.kernels import quantize as _quant
 from repro.kernels import robust as _robust
 
-# CPU backend -> interpret mode.
-INTERPRET = jax.default_backend() == "cpu"
-
 __all__ = [
-    "fedavg", "masked_fedavg", "masked_fedavg_sharded",
+    "interpret_mode", "fedavg", "masked_fedavg", "masked_fedavg_sharded",
     "masked_fedavg_q8", "masked_fedavg_q8_sharded",
     "masked_trimmed_mean", "masked_trimmed_mean_sharded",
     "quantize", "dequantize", "QuantCodec",
 ]
+
+
+def interpret_mode() -> bool:
+    """True unless JAX's default backend is a TPU.
+
+    Read at trace time, so the first traced kernel call (not the import)
+    initializes the backend.  Code that must run on the chip checks it is
+    False; it never silently turns a TPU run into an interpreted one.
+    """
+    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: jax.Array, multiple: int, axis: int = -1) -> tuple[jax.Array, int]:
@@ -49,7 +57,7 @@ def fedavg(stack: jax.Array, weights: jax.Array,
     if block_p is None:
         block_p = _fedavg.choose_block_p(stack.shape[0])
     padded, p = _pad_to(stack, block_p, axis=1)
-    out = _fedavg.fedavg_pallas(padded, weights, block_p=block_p, interpret=INTERPRET)
+    out = _fedavg.fedavg_pallas(padded, weights, block_p=block_p, interpret=interpret_mode())
     return out[:p]
 
 
@@ -68,7 +76,7 @@ def masked_fedavg(arena: jax.Array, weights: jax.Array, mask: jax.Array,
         block_p = _fedavg.choose_block_p_dividing(arena.shape[1], arena.shape[0])
     padded, p = _pad_to(arena, block_p, axis=1)
     out = _fedavg.masked_fedavg_pallas(
-        padded, weights, mask, block_p=block_p, interpret=INTERPRET
+        padded, weights, mask, block_p=block_p, interpret=interpret_mode()
     )
     return out[:p]
 
@@ -97,7 +105,7 @@ def masked_fedavg_q8(arena_q: jax.Array, scales: jax.Array,
     spad, _ = _pad_to(scales, block_p // group, axis=1)
     out = _fused.masked_fedavg_q8_pallas(
         padded, spad, weights, mask, group=group, block_p=block_p,
-        interpret=INTERPRET,
+        interpret=interpret_mode(),
     )
     return out[:p]
 
@@ -116,7 +124,7 @@ def masked_fedavg_q8_sharded(mesh, axes=None, group: int = _quant.DEFAULT_GROUP)
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.aggregation import arena_axes
 
     ax = arena_axes(mesh, axes)
@@ -158,7 +166,7 @@ def masked_trimmed_mean(arena: jax.Array, weights: jax.Array, mask: jax.Array,
         )
     padded, p = _pad_to(arena, block_p, axis=1)
     out = _robust.masked_trimmed_mean_pallas(
-        padded, mask, trim_k=trim_k, block_p=block_p, interpret=INTERPRET
+        padded, mask, trim_k=trim_k, block_p=block_p, interpret=interpret_mode()
     )
     return out[:p]
 
@@ -175,7 +183,7 @@ def masked_trimmed_mean_sharded(mesh, axes=None, trim_k: int = 1):
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.aggregation import arena_axes
 
     ax = arena_axes(mesh, axes)
@@ -204,7 +212,7 @@ def quantize(x: jax.Array, group: int = _quant.DEFAULT_GROUP,
              block_rows: int = _quant.DEFAULT_BLOCK_ROWS):
     """Returns (q, scales); the caller keeps x.shape[0] for dequantize."""
     padded, _ = _pad_to(x, group * block_rows)
-    return _quant.quantize_pallas(padded, group, block_rows, interpret=INTERPRET)
+    return _quant.quantize_pallas(padded, group, block_rows, interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("group", "block_rows", "orig_size"))
@@ -212,7 +220,7 @@ def dequantize(q: jax.Array, scales: jax.Array, orig_size: int,
                group: int = _quant.DEFAULT_GROUP,
                block_rows: int = _quant.DEFAULT_BLOCK_ROWS) -> jax.Array:
     """Inverse of :func:`quantize`, sliced back to ``orig_size`` elements."""
-    x = _quant.dequantize_pallas(q, scales, group, block_rows, interpret=INTERPRET)
+    x = _quant.dequantize_pallas(q, scales, group, block_rows, interpret=interpret_mode())
     return x[:orig_size]
 
 
@@ -231,7 +239,7 @@ def masked_fedavg_sharded(mesh, axes=None):
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core.aggregation import arena_axes
 
     ax = arena_axes(mesh, axes)

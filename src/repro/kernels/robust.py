@@ -16,8 +16,10 @@ so they always rank past the band.
 Degenerate cohorts (``n_valid <= 2 * trim_k``) fall back to the untrimmed
 masked mean of the valid rows, matching
 ``core/aggregation.masked_trimmed_mean`` (the pure-jnp production rule this
-kernel is benchmarked against).  Validated in interpret mode on CPU against
-``ref.masked_trimmed_mean_ref``; the jit wrapper lives in ``ops.py``.
+kernel is benchmarked against).  Numerics are checked in interpret mode
+against ``ref.masked_trimmed_mean_ref``, and the TPU lowering by compiling
+for a described v5e (``tests/test_tpu_compile.py``); the jit wrapper lives
+in ``ops.py``.
 """
 
 from __future__ import annotations
@@ -43,27 +45,31 @@ def _masked_trimmed_mean_kernel(mask_ref, arena_ref, out_ref, *, trim_k):
 
     mask_ref: (N, 1) f32 validity; arena_ref: (N, BP); out_ref: (1, BP).
     """
-    m = mask_ref[:, 0]  # (N,)
+    m = mask_ref[...]  # (N, 1)
     block = arena_ref[...].astype(jnp.float32)  # (N, BP)
     n = block.shape[0]
     # Invalid rows float to +inf: they rank >= n_valid in every column, so
     # the band test below can never admit them (and their garbage — even
     # NaN — never touches the accumulator).
-    x = jnp.where(m[:, None] > 0, block, jnp.inf)
+    x = jnp.where(m > 0, block, jnp.inf)
     n_valid = jnp.sum(m)  # f32 scalar
     row_ids = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)  # (N, BP)
-    zeros = jnp.zeros((x.shape[1],), jnp.float32)
+    zeros = jnp.zeros((1, x.shape[1]), jnp.float32)
 
     def body(i, acc):
         s, c = acc
-        xi = jax.lax.dynamic_slice_in_dim(x, i, 1, axis=0)  # (1, BP)
-        less = jnp.sum(jnp.where(x < xi, 1.0, 0.0), axis=0)  # (BP,)
+        # Row i is re-read from the refs: a dynamic slice of a value has no
+        # TPU lowering, a dynamic sublane offset into a ref does.
+        xi = arena_ref[pl.ds(i, 1), :].astype(jnp.float32)  # (1, BP)
+        xi = jnp.where(mask_ref[pl.ds(i, 1), :] > 0, xi, jnp.inf)
+        less = jnp.sum(jnp.where(x < xi, 1.0, 0.0), axis=0, keepdims=True)
         ties = jnp.sum(
-            jnp.where((x == xi) & (row_ids < i), 1.0, 0.0), axis=0
+            jnp.where((x == xi) & (row_ids < i), 1.0, 0.0),
+            axis=0, keepdims=True,
         )
         rank = less + ties  # distinct per column: a permutation of 0..N-1
         inband = (rank >= trim_k) & (rank < n_valid - trim_k)
-        s = s + jnp.where(inband, xi[0], 0.0)
+        s = s + jnp.where(inband, xi, 0.0)
         c = c + jnp.where(inband, 1.0, 0.0)
         return (s, c)
 
@@ -71,10 +77,11 @@ def _masked_trimmed_mean_kernel(mask_ref, arena_ref, out_ref, *, trim_k):
     trimmed = s / jnp.maximum(c, 1.0)
     # Degenerate cohort: untrimmed masked mean of the valid rows (finite by
     # construction — invalid rows were zeroed, not inf'd, on this path).
-    fb_rows = jnp.where(m[:, None] > 0, block, 0.0)
-    fallback = jnp.sum(fb_rows, axis=0) / jnp.maximum(n_valid, 1.0)
-    out = jnp.where(c > 0, trimmed, jnp.where(n_valid > 0, fallback, 0.0))
-    out_ref[...] = out[None, :]
+    fb_rows = jnp.where(m > 0, block, 0.0)
+    fallback = jnp.sum(fb_rows, axis=0, keepdims=True) / jnp.maximum(n_valid, 1.0)
+    out_ref[...] = jnp.where(
+        c > 0, trimmed, jnp.where(n_valid > 0, fallback, 0.0)
+    )
 
 
 def masked_trimmed_mean_pallas(

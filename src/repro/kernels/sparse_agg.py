@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 __all__ = ["scatter_accumulate", "scatter_accumulate_sharded"]
 

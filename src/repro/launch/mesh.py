@@ -9,9 +9,10 @@ device state (dryrun.py sets XLA_FLAGS before any jax import).
 
 from __future__ import annotations
 
-from repro.compat import make_auto_mesh
-
-__all__ = ["make_production_mesh", "make_debug_mesh", "make_controller_mesh", "HARDWARE"]
+__all__ = [
+    "make_auto_mesh", "make_production_mesh", "make_debug_mesh",
+    "make_controller_mesh", "HARDWARE",
+]
 
 # TPU v5e-class constants used by the roofline analysis (launch/roofline.py).
 HARDWARE = {
@@ -20,6 +21,19 @@ HARDWARE = {
     "ici_link_bandwidth": 50e9,  # per link, B/s
     "hbm_bytes": 16 * 1024**3,  # per chip
 }
+
+
+def make_auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in Auto mode.
+
+    ``jax.make_mesh`` defaults to Explicit axes, which
+    ``with_sharding_constraint`` (``models/sharding.constrain``) rejects; every
+    mesh in this repo is an Auto mesh.
+    """
+    import jax
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCHITECTURES, get_reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.steps import make_serve_step
 from repro.models import kvcache, transformer
 
@@ -37,7 +38,7 @@ def push_to_replicas(
     """Publish model weights to ``n_replicas`` serving hosts, serialize-once.
 
     One ``Channel.broadcast`` serialization, N shared envelopes; each replica
-    deserializes its own copy (one device_put of the whole wire buffer).
+    deserializes its own copy (one batched device_put of the whole tree).
     Prints bytes-on-wire and the broadcast-vs-per-send serialization ratio.
 
     ``replica_upload`` additionally exercises the measured uplink: every
@@ -105,6 +106,9 @@ def main() -> None:
                     help="also echo weights back per replica through the "
                          "measured uplink with this codec")
     args = ap.parse_args()
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}")
 
     cfg = get_reduced(args.arch)
     params = transformer.init_params(jax.random.key(args.seed), cfg)

@@ -26,6 +26,7 @@ import numpy as np
 from repro import optim as optim_mod
 from repro.configs import ARCHITECTURES, get_config, get_reduced
 from repro.core import Driver, FederationEnv, Learner, SelectionPolicy, TerminationCriteria
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data import LMDataIterator, dirichlet_partition, iid_partition, make_housing_data, make_lm_data
 from repro.models import mlp as mlp_model
 from repro.models import transformer
@@ -127,6 +128,9 @@ def main() -> None:
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO, format="%(levelname)s %(name)s: %(message)s")
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    log.info("device: %s %s x%d", dev.platform, dev.device_kind, len(jax.devices()))
 
     if args.arch == "housing-mlp":
         cfg, learners = build_housing_learners(args.size, args.learners, args.seed)

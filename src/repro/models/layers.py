@@ -271,7 +271,7 @@ def _flash_decode(q, ck, cv, k_new, v_new, pos, *, mode: str, window: int,
     q: (B,1,H,hd); ck/cv: (B,L,KVH,hd) sharded (data: B, model: L);
     k_new/v_new: (B,1,KVH,hd).  Returns (out (B,1,H,hd), ck, cv).
     """
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = policy.mesh
@@ -540,7 +540,7 @@ def apply_mla(
     ):
         # absorbed decode over a sequence-sharded latent cache: shard_map
         # flash merge (§Perf cycle 5), latent read-out psum'ed in rkv space.
-        from repro.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as PS
 
         mesh = policy.mesh
@@ -754,7 +754,7 @@ def apply_moe_ep(
     The §Perf hillclimb replaces this with an all-to-all dispatch for the
     train shapes.
     """
-    from repro.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = policy.mesh

@@ -228,8 +228,9 @@ def test_admission_screen_single_host_sync_per_upload(codec, arena_dtype):
     (`recv_upload(..., with_norm=True)` / `recv_upload_quantized`), so the
     only host sync is one `float()` on a scalar the decode already
     scheduled — asserted here by (a) counting scalar readbacks through a
-    proxy and (b) poisoning the separate-norm fallback so any extra norm
-    launch fails the test.
+    proxy and (b) counting launches of the separate-norm program: the raw
+    decode's one program is that norm, the int8 decodes fuse it, and any
+    extra launch (the controller's fallback) fails the test.
     """
     from repro.core import transport
     from repro.core.learner import LocalUpdate
@@ -258,10 +259,15 @@ def test_admission_screen_single_host_sync_per_upload(codec, arena_dtype):
 
     ctrl.channel.recv_upload = spy_recv
     ctrl.channel.recv_upload_quantized = spy_recv_q
-    poison = transport._row_norm
-    transport._row_norm = lambda *_: (_ for _ in ()).throw(
-        AssertionError("separate per-upload norm launch")
-    )
+    real_norm = transport._row_norm
+    launches = {"norm": 0}
+
+    def counting_norm(row):
+        launches["norm"] += 1
+        return real_norm(row)
+
+    transport._row_norm = counting_norm
+    norm_launches = 1 if codec == "raw" else 0
     try:
         rng = np.random.default_rng(0)
         P = ctrl.arena.padded_params
@@ -269,7 +275,7 @@ def test_admission_screen_single_host_sync_per_upload(codec, arena_dtype):
             row = jnp.asarray(rng.normal(size=P), jnp.float32)
             env = ctrl.channel.upload(
                 row, metadata={"learner_id": f"l{k % 2}", "round_id": 0})
-            before = counter["readbacks"]
+            before, norms_before = counter["readbacks"], launches["norm"]
             ctrl.ingest(LocalUpdate(
                 learner_id=f"l{k % 2}", round_id=0, params=None, buffer=None,
                 num_examples=10, metrics={}, seconds_per_step=0.01,
@@ -277,8 +283,10 @@ def test_admission_screen_single_host_sync_per_upload(codec, arena_dtype):
             ))
             assert counter["readbacks"] - before == 1, \
                 "expected exactly one scalar readback per upload"
+            assert launches["norm"] - norms_before == norm_launches, \
+                "separate per-upload norm launch"
     finally:
-        transport._row_norm = poison
+        transport._row_norm = real_norm
         ctrl.shutdown()
     if arena_dtype == "int8" and codec == "int8":
         assert ctrl.telemetry.value("engine.uploads.quantized_direct", 0) == 4

@@ -16,6 +16,7 @@ Covers the engine refactor's acceptance surface:
 
 import random
 import threading
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -149,6 +150,73 @@ def test_event_ordering_hammer_16_threads():
     assert isinstance(log[last_agg + 1], Evaluated)
     dispatched = [e for e in log if isinstance(e, Dispatched)]
     assert len(dispatched) == n * rounds
+
+
+class _OverlapLearner(Learner):
+    """Counts the learner tasks (fit or evaluate) running at once.
+
+    Each fit waits up to ``linger_s`` for all ``n`` learners to be inside
+    one, so tasks that may overlap do, and tasks held one at a time finish.
+    """
+
+    def __init__(self, lid, live, n, pad_to, linger_s=0.3):
+        dummy = lambda *a, **k: None  # noqa: E731
+        super().__init__(lid, dummy, dummy, dummy, dummy, sgd(0.1), 1)
+        self._live, self._n, self._pad_to = live, n, pad_to
+        self._linger_s = linger_s
+
+    def _enter(self):
+        with self._live["lock"]:
+            self._live["now"] += 1
+            self._live["most"] = max(self._live["most"], self._live["now"])
+
+    def _leave(self):
+        with self._live["lock"]:
+            self._live["now"] -= 1
+
+    def fit(self, params, task):
+        self._enter()
+        deadline = time.monotonic() + self._linger_s
+        while self._live["now"] < self._n and time.monotonic() < deadline:
+            time.sleep(0.005)
+        self._leave()
+        return LocalUpdate(
+            learner_id=self.learner_id, round_id=task.round_id,
+            params=None, num_examples=1, metrics={}, seconds_per_step=1e-4,
+            buffer=jnp.ones((self._pad_to,), jnp.float32),
+        )
+
+    def evaluate(self, params, round_id):
+        self._enter()
+        self._leave()
+        return EvalReport(self.learner_id, round_id, {"eval_loss": 0.0}, 1)
+
+
+@pytest.mark.parametrize("backend, most", [("cpu", 4), ("tpu", 1)])
+def test_learner_tasks_one_at_a_time_on_an_accelerator(monkeypatch, backend, most):
+    """In-process learners share JAX's default device: on an accelerator the
+    engine runs their tasks one at a time, so N model copies never sit in
+    device memory at once; on the CPU backend the executor alone bounds
+    them."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    n = 4
+    live = {"lock": threading.Lock(), "now": 0, "most": 0}
+    ctrl = Controller(
+        protocol=SyncProtocol(local_steps=1, batch_size=1),
+        max_dispatch_workers=n, arena_n_max=n, admission_control=False,
+    )
+    ctrl.set_initial_model({"w": jnp.zeros((8,), jnp.float32)})
+    for i in range(n):
+        ctrl.register_learner(_OverlapLearner(f"l{i}", live, n, 1024))
+    ctrl.engine.run(rounds=1)
+    ctrl.shutdown()
+    assert ctrl.arena.total_writes == n
+    assert live["most"] == most
+    np.testing.assert_array_equal(
+        np.asarray(ctrl.global_params["w"]), np.ones((8,), np.float32)
+    )
 
 
 def test_engine_run_argument_contract():

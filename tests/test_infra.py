@@ -115,3 +115,48 @@ def test_checkpoint_restore_latest(tmp_path):
 def test_checkpoint_missing_raises(tmp_path):
     with pytest.raises(FileNotFoundError):
         restore_checkpoint(str(tmp_path))
+
+
+# -- entry-point plumbing ------------------------------------------------------
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_compile_cache_keeps_the_env_dir(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_the_checkout(monkeypatch):
+    from repro.launch import compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        got = compile_cache.enable_compile_cache()
+        assert got == os.path.join(_REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_library_import_starts_no_backend_and_no_cache():
+    import subprocess
+    import sys
+
+    code = (
+        "import jax, repro.core, repro.kernels.ops, repro.launch.train\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+        "assert jax.config.jax_compilation_cache_dir is None\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(_REPO, "src")] + env.get("PYTHONPATH", "").split(os.pathsep)
+    )
+    subprocess.run([sys.executable, "-c", code], env=env, check=True, timeout=120)

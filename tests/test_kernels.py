@@ -1,7 +1,9 @@
 """Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles.
 
-Kernels execute in interpret mode on CPU (the TPU lowering is proven
-structurally by pl.pallas_call + BlockSpec; numerics validated here).
+Kernels execute in interpret mode on CPU, which checks numerics only: it
+does not apply the TPU's tiling or VMEM limits.  That the kernels lower for
+the chip is checked by compiling them for a described v5e
+(``tests/test_tpu_compile.py``), and on the chip by ``chip_smoke.py``.
 """
 
 import jax

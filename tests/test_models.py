@@ -150,7 +150,9 @@ def test_moe_ep_matches_dense_oracle():
     p = layers.init_moe(jax.random.key(0), cfg)
     x = jax.random.normal(jax.random.key(1), (2, 8, 32), jnp.float32)
     y_dense, aux_d = layers.apply_moe_dense(p, x, cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_auto_mesh
+
+    mesh = make_auto_mesh((1, 1), ("data", "model"))
     pol = make_policy(cfg, mesh)
     y_ep, aux_e = jax.jit(lambda p_, x_: layers.apply_moe_ep(p_, x_, cfg, pol))(p, x)
     np.testing.assert_allclose(y_dense, y_ep, atol=1e-4)

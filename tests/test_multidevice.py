@@ -35,9 +35,10 @@ def _run(script: str) -> str:
 def test_fedavg_sharded_no_collectives():
     _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_auto_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core.aggregation import weighted_average
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_auto_mesh((4, 2), ("data", "model"))
         stack = jax.random.normal(jax.random.key(0), (5, 4096), jnp.float32)
         w = jnp.arange(1., 6.)
         fn = jax.jit(
@@ -62,8 +63,9 @@ def test_fedavg_sharded_no_collectives():
 def test_hierarchical_pod_fedavg():
     _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_auto_mesh
         from repro.core.aggregation import hierarchical_fedavg, weighted_average
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
         n_pods, P_ = 2, 1024
         stack = jax.random.normal(jax.random.key(0), (n_pods, P_), jnp.float32)
         w = jnp.asarray([1.0, 3.0])
@@ -79,6 +81,7 @@ def test_hierarchical_pod_fedavg():
 def test_moe_ep_on_2x2_mesh_matches_dense():
     _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_auto_mesh
         from repro.models.config import ModelConfig
         from repro.models import layers
         from repro.models.sharding import make_policy
@@ -89,7 +92,7 @@ def test_moe_ep_on_2x2_mesh_matches_dense():
         p = layers.init_moe(jax.random.key(0), cfg)
         x = jax.random.normal(jax.random.key(1), (4, 8, 32), jnp.float32)
         y_dense, _ = layers.apply_moe_dense(p, x, cfg)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_auto_mesh((2, 2), ("data", "model"))
         pol = make_policy(cfg, mesh)
         with mesh:
             y_ep, _ = jax.jit(lambda pp, xx: layers.apply_moe_ep(pp, xx, cfg, pol))(p, x)
@@ -102,6 +105,7 @@ def test_moe_ep_on_2x2_mesh_matches_dense():
 def test_reduced_train_step_on_pod_mesh():
     _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs import get_reduced
         from repro.launch.specs import input_specs
         from repro.launch.steps import make_train_step
@@ -110,7 +114,7 @@ def test_reduced_train_step_on_pod_mesh():
         from repro.optim import sgd
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_auto_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get_reduced("qwen3-14b")
         pol = make_policy(cfg, mesh, multi_pod=True, fsdp=True)
         params = transformer.init_params(jax.random.key(0), cfg)
@@ -133,12 +137,13 @@ def test_reduced_train_step_on_pod_mesh():
 def test_serve_step_with_sharded_cache():
     _run("""
         import jax, jax.numpy as jnp
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs import get_reduced
         from repro.launch.steps import make_serve_step
         from repro.models import kvcache, transformer
         from repro.models.sharding import make_policy
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_auto_mesh((2, 2), ("data", "model"))
         cfg = get_reduced("gemma3-4b")
         pol = make_policy(cfg, mesh)
         params = transformer.init_params(jax.random.key(0), cfg)
@@ -160,11 +165,12 @@ def test_flash_decode_matches_unsharded():
     decode path — GQA + sliding + MLA, on a real (2,2) mesh."""
     _run("""
         import dataclasses, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs import get_reduced
         from repro.models import kvcache, transformer
         from repro.models.sharding import make_policy
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_auto_mesh((2, 2), ("data", "model"))
         for arch in ("gemma3-4b", "deepseek-v3-671b", "qwen3-14b"):
             cfg = dataclasses.replace(get_reduced(arch), dtype=jnp.float32)
             pol = make_policy(cfg, mesh)
@@ -202,11 +208,12 @@ def test_moe_2d_decode_matches_unsharded():
     match the single-device decode output."""
     _run("""
         import dataclasses, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_auto_mesh
         from repro.configs import get_reduced
         from repro.models import kvcache, transformer
         from repro.models.sharding import make_policy
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_auto_mesh((2, 2), ("data", "model"))
         cfg = dataclasses.replace(get_reduced("deepseek-v3-671b"), dtype=jnp.float32,
                                   mtp_depth=0)
         pol = make_policy(cfg, mesh, fsdp=True, serving=True)

@@ -48,7 +48,7 @@ def _as_arrays(rows, weights):
     return arena, w, mask
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(data=_arenas())
 def test_masked_median_equals_dense_under_full_mask(data):
     rows, weights = data
@@ -58,7 +58,7 @@ def test_masked_median_equals_dense_under_full_mask(data):
     np.testing.assert_allclose(masked, dense, rtol=1e-6, atol=1e-6)
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(data=_arenas(min_rows=3))
 def test_masked_trimmed_mean_equals_dense_under_full_mask(data):
     rows, weights = data
@@ -68,7 +68,7 @@ def test_masked_trimmed_mean_equals_dense_under_full_mask(data):
     np.testing.assert_allclose(masked, dense, rtol=1e-5, atol=1e-5)
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(data=_arenas(min_rows=3), seed=st.integers(min_value=0, max_value=999))
 def test_row_permutation_invariance(data, seed):
     rows, weights = data
@@ -86,7 +86,7 @@ def test_row_permutation_invariance(data, seed):
         )
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(data=_arenas(min_rows=3))
 def test_trimmed_mean_stays_inside_valid_envelope(data):
     rows, weights = data
@@ -97,7 +97,7 @@ def test_trimmed_mean_stays_inside_valid_envelope(data):
     assert np.all(out >= lo - 1e-5) and np.all(out <= hi + 1e-5)
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(
     data=_arenas(min_rows=3, max_rows=7),
     bad_value=st.floats(min_value=-1e6, max_value=1e6),
@@ -118,7 +118,7 @@ def test_median_resists_minority_corruption(data, bad_value):
     assert np.all(med >= lo - 1e-4) and np.all(med <= hi + 1e-4)
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(
     data=_arenas(min_rows=3, max_rows=5),
     bad_value=st.floats(min_value=-1e6, max_value=1e6),
@@ -142,7 +142,7 @@ def test_trimmed_mean_discards_extremes_it_was_sized_for(data, bad_value, n_bad)
     assert np.all(out >= lo - 1e-4) and np.all(out <= hi + 1e-4)
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(data=_arenas(min_rows=4))
 def test_invalid_rows_never_influence_the_reduce(data):
     """Garbage (NaN / 1e30) in masked-out rows must not leak: the masked
