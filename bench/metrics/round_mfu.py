@@ -1,0 +1,10 @@
+"""The window's share of the chip's bf16 peak, in %.
+
+Training and evaluation operations of every learner task in the window
+(the family's ``learner_flops``, from ``bench/counts.py``) over the
+window's host-clock length times the peak of ``bench/peaks.json``.
+"""
+
+
+def read(ctx):
+    return 100.0 * ctx.flops() / (ctx.window_s * ctx.peak("bf16_flops_per_s"))
