@@ -41,7 +41,6 @@ from __future__ import annotations
 
 import math
 import threading
-import time
 from typing import Any, Callable
 
 import jax
@@ -816,9 +815,13 @@ class Controller:
         return self.channel.recv_upload(envelope, with_norm=with_norm)
 
     def _screen_norm(
-        self, learner_id: str, norm: float
+        self, learner_id: str, norm: Any, round_id: int | None = None
     ) -> tuple[float | None, dict | None]:
-        """The admission decision on an already-materialized norm scalar.
+        """The admission decision on the row's norm.
+
+        ``norm`` is a device scalar (or a float).  Reading it back is the
+        screen's one blocking host sync per upload, timed by the
+        ``controller.screen`` span.
 
         A single NaN/inf anywhere in the row makes its norm non-finite
         (reject with :class:`UploadRejectedError`; counted in
@@ -835,6 +838,9 @@ class Controller:
         row passes untouched), ``clip_info`` is ``None`` or
         ``{"norm": original, "limit": applied}``.
         """
+        with self.telemetry.span("controller.screen", round=round_id,
+                                 learner=learner_id):
+            norm = float(norm)
         if not math.isfinite(norm):
             self._c_rejected_nonfinite.add(1)
             raise UploadRejectedError(learner_id, "nonfinite", norm)
@@ -863,6 +869,7 @@ class Controller:
         learner_id: str,
         buffer: jax.Array,
         norm: jax.Array | None = None,
+        round_id: int | None = None,
     ) -> tuple[jax.Array, dict | None]:
         """The admission screen: reject non-finite rows, clip norm outliers.
 
@@ -879,7 +886,7 @@ class Controller:
         """
         if norm is None:
             norm = transport._row_norm(buffer)
-        scale, clip = self._screen_norm(learner_id, float(norm))
+        scale, clip = self._screen_norm(learner_id, norm, round_id)
         if scale is not None:
             buffer = buffer * jnp.asarray(scale, buffer.dtype)
         return buffer, clip
@@ -919,7 +926,21 @@ class Controller:
         (:math:`\\sqrt{\\sum_g s_g^2 \\sum_i q_{g,i}^2}`) and clipping
         rescales the scales vector.  Counted in
         ``engine.uploads.quantized_direct``.
+
+        Timed by the ``controller.ingest`` span; inside it the screen's
+        readback is ``controller.screen`` and the row write (enqueued)
+        ``controller.write``.
         """
+        with self._span("controller.ingest", update):
+            return self._ingest(update)
+
+    def _span(self, name: str, update: LocalUpdate):
+        """The span ``name`` carrying ``update``'s round and learner."""
+        return self.telemetry.span(
+            name, round=update.round_id, learner=update.learner_id
+        )
+
+    def _ingest(self, update: LocalUpdate) -> dict | None:
         clip: dict | None = None
         if self.store_mode == "arena":
             if self._sparse_direct_ok(update):
@@ -928,22 +949,23 @@ class Controller:
                 )
                 if self.admission_control:
                     scale, clip = self._screen_norm(
-                        update.learner_id, float(norm)
+                        update.learner_id, norm, update.round_id
                     )
                     if scale is not None:
                         # Clipping a sparse row == rescaling its values
                         # (top-k indices are unique, so the value-vector
                         # norm *is* the row norm).
                         val = val * jnp.float32(scale)
-                self.arena.write_sparse(
-                    update.learner_id,
-                    idx,
-                    val,
-                    weight=float(update.num_examples),
-                    version=float(
-                        self._learner_versions.get(update.learner_id, 0)
-                    ),
-                )
+                with self._span("controller.write", update):
+                    self.arena.write_sparse(
+                        update.learner_id,
+                        idx,
+                        val,
+                        weight=float(update.num_examples),
+                        version=float(
+                            self._learner_versions.get(update.learner_id, 0)
+                        ),
+                    )
                 self._c_sparse_direct.add(1)
             elif (self.arena is not None
                     and self.arena.arena_dtype == "topk"):
@@ -958,20 +980,21 @@ class Controller:
                 )
                 if self.admission_control:
                     scale, clip = self._screen_norm(
-                        update.learner_id, float(norm)
+                        update.learner_id, norm, update.round_id
                     )
                     if scale is not None:
                         # Clipping a quantized row == rescaling its scales.
                         scales = scales * jnp.float32(scale)
-                self.arena.write_quantized(
-                    update.learner_id,
-                    q,
-                    scales,
-                    weight=float(update.num_examples),
-                    version=float(
-                        self._learner_versions.get(update.learner_id, 0)
-                    ),
-                )
+                with self._span("controller.write", update):
+                    self.arena.write_quantized(
+                        update.learner_id,
+                        q,
+                        scales,
+                        weight=float(update.num_examples),
+                        version=float(
+                            self._learner_versions.get(update.learner_id, 0)
+                        ),
+                    )
                 self._c_quant_direct.add(1)
             else:
                 if self.admission_control:
@@ -980,31 +1003,34 @@ class Controller:
                         with_norm=True,
                     )
                     buffer, clip = self._screen_upload(
-                        update.learner_id, buffer, norm=norm
+                        update.learner_id, buffer, norm=norm,
+                        round_id=update.round_id,
                     )
                 else:
                     buffer = self._upload_buffer(
                         update, pad_to=self.arena.padded_params
                     )
-                self.arena.write(
-                    update.learner_id,
-                    buffer,
-                    weight=float(update.num_examples),
-                    version=float(
-                        self._learner_versions.get(update.learner_id, 0)
-                    ),
-                )
+                with self._span("controller.write", update):
+                    self.arena.write(
+                        update.learner_id,
+                        buffer,
+                        weight=float(update.num_examples),
+                        version=float(
+                            self._learner_versions.get(update.learner_id, 0)
+                        ),
+                    )
         else:
             if self.admission_control:
                 buffer, norm = self._upload_buffer(
                     update, pad_to=None, with_norm=True
                 )
                 buffer, clip = self._screen_upload(
-                    update.learner_id, buffer, norm=norm
+                    update.learner_id, buffer, norm=norm,
+                    round_id=update.round_id,
                 )
             else:
                 buffer = self._upload_buffer(update, pad_to=None)
-            with self._store_lock:
+            with self._span("controller.write", update), self._store_lock:
                 self.store.insert(
                     ModelRecord(
                         learner_id=update.learner_id,
@@ -1146,6 +1172,21 @@ class Controller:
         self._model_version += 1
         self._g_version.set(self._model_version)
 
+    def _reduce_and_commit(self, reduce_fn: Callable, *args: Any) -> float:
+        """``reduce_fn(*args)``, then :meth:`_commit` of what it returns.
+
+        The reduce runs under the ``controller.reduce`` span (it enqueues),
+        the commit under ``controller.commit`` (it waits for the reduce and
+        the server step on the device).  Returns the two spans' seconds.
+        """
+        with self.telemetry.span("controller.reduce",
+                                 round=self.round_id) as reduce:
+            new_buffer = reduce_fn(*args)
+        with self.telemetry.span("controller.commit",
+                                 round=self.round_id) as commit:
+            self._commit(new_buffer)
+        return reduce.seconds + commit.seconds
+
     def _mask_session_seed(self, epoch: int) -> int:
         """The per-epoch secure mask session (round id / model version key)."""
         from repro.core import secure as secure_mod
@@ -1162,7 +1203,10 @@ class Controller:
         Secure mode sums mask-encoded fixed-point rows in a per-round mask
         session.  Commits the result; returns the aggregation seconds.
         """
-        t0 = time.perf_counter()
+        return self._reduce_and_commit(self._reduce_round, selected)
+
+    def _reduce_round(self, selected: list[str]) -> jax.Array:
+        """The reduce of :meth:`aggregate_round`, not yet committed."""
         if self.store_mode == "arena":
             new_buffer = self._aggregate_arena(selected)
         else:
@@ -1186,8 +1230,7 @@ class Controller:
                     [float(r.num_examples) for r in records], jnp.float32
                 )
                 new_buffer = self.aggregate_fn(stack, weights)
-        self._commit(new_buffer)
-        return time.perf_counter() - t0
+        return new_buffer
 
     def _aggregate_arena(self, selected: list[str]) -> jax.Array:
         """Masked reduction over the arena restricted to the round's cohort."""
@@ -1319,8 +1362,11 @@ class Controller:
         the controller still never sees an individual model.  Commits the
         result; returns the aggregation seconds.
         """
+        return self._reduce_and_commit(self._reduce_community)
+
+    def _reduce_community(self) -> jax.Array:
+        """The reduce of :meth:`aggregate_community`, not yet committed."""
         alpha = getattr(self.protocol, "staleness_alpha", 0.5)
-        t0 = time.perf_counter()
         if self.store_mode == "arena":
             arena = self.arena
             with arena.lock:
@@ -1372,8 +1418,7 @@ class Controller:
                 stack = jnp.stack([r.buffer for r in records], axis=0)
                 w = aggregation.staleness_weights(n_ex, stal, alpha)
                 new_buffer = self.aggregate_fn(stack, w)
-        self._commit(new_buffer)
-        return time.perf_counter() - t0
+        return new_buffer
 
     def aggregate_buffer(self, members: list[str]) -> float:
         """One FedBuff community update over exactly the buffered members.
@@ -1387,10 +1432,13 @@ class Controller:
         (not arrival order), so the reduce is deterministic under any
         executor interleaving.  Commits the result; returns the seconds.
         """
+        return self._reduce_and_commit(self._reduce_buffer, members)
+
+    def _reduce_buffer(self, members: list[str]) -> jax.Array:
+        """The reduce of :meth:`aggregate_buffer`, not yet committed."""
         alpha = getattr(self.protocol, "staleness_alpha", 0.5)
         wanted = set(members)
         ordered = [lid for lid in self._learners if lid in wanted]
-        t0 = time.perf_counter()
         if not ordered:
             raise RuntimeError("no local models available to aggregate")
         if self.store_mode == "arena":
@@ -1450,8 +1498,7 @@ class Controller:
                 stack = jnp.stack([r.buffer for r in records], axis=0)
                 w = aggregation.staleness_weights(n_ex, stal, alpha)
                 new_buffer = self.aggregate_fn(stack, w)
-        self._commit(new_buffer)
-        return time.perf_counter() - t0
+        return new_buffer
 
     def _secure_community_arena(
         self, alpha: float, members: list[str] | None = None

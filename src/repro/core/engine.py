@@ -336,6 +336,11 @@ class RoundEngine:
         """Thread-safe: enqueue an event for the engine loop (arrival order)."""
         self._events.put(event)
 
+    def _next_event(self) -> Any:
+        """The loop's next event; its wait is the ``engine.wait`` span."""
+        with self.telemetry.span("engine.wait", round=self.controller.round_id):
+            return self._events.get()
+
     def _log(self, event: Any, **context: Any) -> None:
         # Processing order == log order: only the loop thread appends.  The
         # journal gets the same event plus engine-attached context (byte
@@ -367,6 +372,21 @@ class RoundEngine:
             )
         return self._device_slots
 
+    @contextlib.contextmanager
+    def _task_slot(self, slots, submitted: float, **ids: Any):
+        """Hold a learner task's device slot (see :meth:`_learner_slots`).
+
+        The ``engine.task.wait`` span runs from the task's submission
+        (``submitted``, read on the loop thread) to the moment its worker
+        holds the slot: the executor's queue and the slot's wait.
+        """
+        with self.telemetry.span("engine.task.wait", start=submitted, **ids):
+            slots.__enter__()
+        try:
+            yield
+        finally:
+            slots.__exit__(None, None, None)
+
     def _submit(self, lid: str, task: TrainTask, envelope: Any) -> None:
         """Fire-and-forget one task: recv + fit on a worker, post the arrival."""
         c = self.controller
@@ -376,10 +396,12 @@ class RoundEngine:
         # KeyError surfacing from the worker.
         learner = c._learners[lid]
         slots = self._learner_slots()
+        submitted = time.perf_counter()
 
         def work() -> None:
             try:
-                with slots:
+                with self._task_slot(slots, submitted, kind="train",
+                                     round=task.round_id, learner=lid):
                     params = c.channel.recv(envelope)
                     update = learner.fit(params, task)
                 self.post(UploadArrived(update=update))
@@ -398,7 +420,9 @@ class RoundEngine:
         task = c.protocol.size_task(
             c.round_id, c._learner_profiles[lid], wire_s=c.wire_time_s(lid)
         )
-        envelope = broadcast.to({"task": task, "learner_id": lid})
+        envelope = broadcast.to(
+            {"task": task, "learner_id": lid, "round_id": c.round_id}
+        )
         self._submit(lid, task, envelope)
         self._log(
             Dispatched(round_id=c.round_id, learner_id=lid, task=task),
@@ -459,10 +483,12 @@ class RoundEngine:
             # path's empty-cohort error).
             raise RuntimeError("no learners selected for dispatch")
         state.t_train = time.perf_counter()
-        broadcast = c._broadcast() if state.cohort else None
-        for lid in state.cohort:
-            self._dispatch_one(lid, broadcast)
-        state.timings.train_dispatch_s = time.perf_counter() - state.t_train
+        with self.telemetry.span("engine.dispatch", round=state.round_id,
+                                 kind="train") as dispatch:
+            broadcast = c._broadcast() if state.cohort else None
+            for lid in state.cohort:
+                self._dispatch_one(lid, broadcast)
+        state.timings.train_dispatch_s = dispatch.seconds
         deadline = getattr(c.protocol, "deadline_s", None)
         if (not continuous and deadline is not None
                 and getattr(c.protocol, "enforce_wall_clock", False)):
@@ -481,25 +507,36 @@ class RoundEngine:
 
         Shares the post-aggregation model's single serialization with the
         next round's train dispatch (both read the same version's broadcast).
+        The ``engine.evaluate`` span times the fan-out up to the last report
+        (``eval_round_s``); its ``engine.dispatch`` span (``kind="eval"``)
+        the broadcast and the submissions (``eval_dispatch_s``).
         """
         c = self.controller
-        t0 = time.perf_counter()
-        broadcast = c._broadcast()
-        slots = self._learner_slots()
-        futures = []
-        # Members that deregistered mid-round are skipped, not fatal.
-        for lid in [x for x in state.cohort if x in c._learners]:
-            envelope = broadcast.to({"eval": True})
+        rid = c.round_id
+        tel = self.telemetry
+        with tel.span("engine.evaluate", round=rid) as evaluate:
+            with tel.span("engine.dispatch", round=rid, kind="eval") as dispatch:
+                broadcast = c._broadcast()
+                slots = self._learner_slots()
+                futures = []
+                # Members that deregistered mid-round are skipped, not fatal.
+                for lid in [x for x in state.cohort if x in c._learners]:
+                    envelope = broadcast.to(
+                        {"eval": True, "learner_id": lid, "round_id": rid}
+                    )
+                    submitted = time.perf_counter()
 
-            def run(lid=lid, envelope=envelope) -> EvalReport:
-                with slots:
-                    params = c.channel.recv(envelope)
-                    return c._learners[lid].evaluate(params, c.round_id)
+                    def run(lid=lid, envelope=envelope,
+                            submitted=submitted) -> EvalReport:
+                        with self._task_slot(slots, submitted, kind="eval",
+                                             round=rid, learner=lid):
+                            params = c.channel.recv(envelope)
+                            return c._learners[lid].evaluate(params, rid)
 
-            futures.append(self._executor.submit(run))
-        state.timings.eval_dispatch_s = time.perf_counter() - t0
-        reports = [f.result() for f in futures]
-        state.timings.eval_round_s = time.perf_counter() - t0
+                    futures.append(self._executor.submit(run))
+            reports = [f.result() for f in futures]
+        state.timings.eval_dispatch_s = dispatch.seconds
+        state.timings.eval_round_s = evaluate.seconds
         state.timings.metrics = reduce_eval(reports)
         self._log(Evaluated(round_id=state.round_id, metrics=state.timings.metrics))
 
@@ -559,7 +596,7 @@ class RoundEngine:
             # state written by a checkpoint is quiescent: nothing the golden
             # run will later fold in depends on an unsaved model version.
             while self._outstanding > 0:
-                ev = self._events.get()
+                ev = self._next_event()
                 if isinstance(ev, UploadArrived):
                     handle_upload(ev, fire=False)
                 else:
@@ -608,7 +645,10 @@ class RoundEngine:
             )
             self.aggregates_fired += 1
             state.timings.train_round_s = time.perf_counter() - state.t_train
-            state.timings.aggregation_s = self._aggregate(state)
+            with self.telemetry.span("engine.aggregate",
+                                     round=state.round_id) as aggregate:
+                self._aggregate(state)
+            state.timings.aggregation_s = aggregate.seconds
             state.aggregated = True
             self._evaluate(state)
             state.timings.federation_round_s = time.perf_counter() - state.t_round
@@ -664,7 +704,10 @@ class RoundEngine:
                 )
                 self.aggregates_fired += 1
                 timings = RoundTimings(round_id=c.round_id)
-                timings.aggregation_s = self._aggregate(state, members)
+                with self.telemetry.span("engine.aggregate",
+                                         round=c.round_id) as aggregate:
+                    self._aggregate(state, members)
+                timings.aggregation_s = aggregate.seconds
                 timings.federation_round_s = timings.aggregation_s
                 out.append(timings)
                 c.history.append(timings)
@@ -851,7 +894,7 @@ class RoundEngine:
             # target is met AND nothing is in flight or queued.
             while (completed < target or self._outstanding > 0
                    or not self._events.empty()):
-                event = self._events.get()
+                event = self._next_event()
                 if isinstance(event, UploadArrived):
                     handle_upload(event)
                 elif isinstance(event, DeadlineExpired):
@@ -900,8 +943,8 @@ class RoundEngine:
         late, self._late_carry = self._late_carry, []
         return late
 
-    def _aggregate(self, state: _RoundState, members: tuple | None = None) -> float:
-        """Reduce per the policy's weighting hook; returns the agg seconds.
+    def _aggregate(self, state: _RoundState, members: tuple | None = None) -> None:
+        """Reduce per the policy's weighting hook and commit the result.
 
         ``aggregate_scope == "buffer"`` (FedBuff) reduces exactly the
         buffered ``members``; ``"staleness"`` aggregates every valid stored
@@ -912,16 +955,17 @@ class RoundEngine:
         """
         c = self.controller
         if getattr(c.protocol, "aggregate_scope", None) == "buffer":
-            return c.aggregate_buffer(list(members or ()))
-        if c.protocol.weighting() == "staleness":
-            return c.aggregate_community()
-        live = [lid for lid in state.cohort if lid in state.arrived_ids]
-        seen = set(live)
-        extras = [
-            lid for lid in self._take_late()
-            if lid not in seen and lid in c._learners
-        ]
-        return c.aggregate_round(live + extras)
+            c.aggregate_buffer(list(members or ()))
+        elif c.protocol.weighting() == "staleness":
+            c.aggregate_community()
+        else:
+            live = [lid for lid in state.cohort if lid in state.arrived_ids]
+            seen = set(live)
+            extras = [
+                lid for lid in self._take_late()
+                if lid not in seen and lid in c._learners
+            ]
+            c.aggregate_round(live + extras)
 
     def _abort(self) -> None:
         """Leave the engine re-runnable after an error escapes the loop.

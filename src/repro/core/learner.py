@@ -16,13 +16,13 @@ global-model envelopes from the controller.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Iterator
 
 import jax
 import jax.numpy as jnp
 
 from repro.core import packing
+from repro.core.metrics import Telemetry
 from repro.core.scheduler import TrainTask
 from repro.optim import Optimizer, apply_fedprox
 
@@ -73,6 +73,10 @@ class Learner:
     _size) -> batch`` and ``eval_data_fn()`` supply private data.  All model
     structure lives in the loss function — the learner is model-agnostic,
     like MetisFL's learner wrapper around user fit/evaluate functions.
+
+    ``telemetry`` holds the learner's spans (``learner.fit``,
+    ``learner.steps``, ``learner.pack``, ``learner.evaluate``): its own
+    registry until the controller registers it, the federation's after.
     """
 
     def __init__(
@@ -102,6 +106,7 @@ class Learner:
         # None until the first sparse upload; rides checkpoints via
         # export_residual/restore_residual.
         self._residual: jax.Array | None = None
+        self.telemetry = Telemetry()
 
     # -- wire contract ------------------------------------------------------
     def accept_manifest(
@@ -121,6 +126,7 @@ class Learner:
         self._manifest = manifest
         self._upload_pad = pad_to
         self._channel = channel
+        self.telemetry = getattr(channel, "telemetry", None) or self.telemetry
 
     # -- heartbeat ----------------------------------------------------------
     def ping(self) -> bool:
@@ -187,11 +193,9 @@ class Learner:
         )
         idx, val = codec.unpack_coords(upload.payload, int(acc.shape[0]))
         self._residual = topk_kernels.ef_residual(acc, idx, val)
-        telemetry = getattr(self._channel, "telemetry", None)
-        if telemetry is not None:
-            telemetry.gauge("learner.residual_norm").set(
-                float(jnp.linalg.norm(self._residual))
-            )
+        self.telemetry.gauge("learner.residual_norm").set(
+            float(jnp.linalg.norm(self._residual))
+        )
         return upload
 
     def export_residual(self) -> Any | None:
@@ -213,7 +217,18 @@ class Learner:
         )
 
     def fit(self, params: Any, task: TrainTask) -> LocalUpdate:
-        """Run ``task.local_steps`` local optimization steps (paper T2-T3)."""
+        """Run ``task.local_steps`` local optimization steps (paper T2-T3).
+
+        Timed by the ``learner.fit`` span.  Inside it, ``learner.steps``
+        runs up to the steps' completion on the device (its seconds over
+        the step count are ``seconds_per_step``) and ``learner.pack``
+        enqueues the upload row's packing.
+        """
+        ids = {"round": task.round_id, "learner": self.learner_id}
+        with self.telemetry.span("learner.fit", **ids):
+            return self._fit(params, task, ids)
+
+    def _fit(self, params: Any, task: TrainTask, ids: dict) -> LocalUpdate:
         step = self._make_step(task.prox_mu, params)
         opt_state = self._optimizer.init(params)
         losses = []
@@ -225,18 +240,18 @@ class Learner:
             # the controller broadcast (async-safe — the controller no
             # longer holds every learner's base version).
             base = packing.pack_numeric(params, pad_to=self._upload_pad)
-        t0 = time.perf_counter()
-        for _ in range(task.local_steps):
-            batch = self._data_fn(task.batch_size)
-            params, opt_state, loss = step(params, opt_state, batch)
-        jax.block_until_ready(loss)
-        elapsed = time.perf_counter() - t0
+        with self.telemetry.span("learner.steps", **ids) as steps:
+            for _ in range(task.local_steps):
+                batch = self._data_fn(task.batch_size)
+                params, opt_state, loss = step(params, opt_state, batch)
+            jax.block_until_ready(loss)
         losses.append(float(loss))
         buffer = upload = None
         if self._manifest is not None:
             # Flat-buffer upload fast path: pack learner-side (off the
             # controller's arrival path), padded to the arena row width.
-            buffer = packing.pack_numeric(params, pad_to=self._upload_pad)
+            with self.telemetry.span("learner.pack", **ids):
+                buffer = packing.pack_numeric(params, pad_to=self._upload_pad)
             if self._channel is not None:
                 # Measured uplink: the packed row crosses the channel as a
                 # codec-encoded wire envelope; the in-process buffer is
@@ -258,16 +273,22 @@ class Learner:
             params=params,
             num_examples=self.num_examples,
             metrics={"train_loss": losses[-1], "local_steps": task.local_steps},
-            seconds_per_step=elapsed / max(task.local_steps, 1),
+            seconds_per_step=steps.seconds / max(task.local_steps, 1),
             buffer=buffer,
             upload=upload,
         )
 
     # -- evaluation ---------------------------------------------------------
     def evaluate(self, params: Any, round_id: int) -> EvalReport:
-        """Synchronous EvaluateModel over the learner's private eval data."""
-        batch = self._eval_data_fn()
-        metrics = {k: float(v) for k, v in self._eval_fn(params, batch).items()}
+        """Synchronous EvaluateModel over the learner's private eval data.
+
+        Timed by the ``learner.evaluate`` span.
+        """
+        with self.telemetry.span("learner.evaluate", round=round_id,
+                                 learner=self.learner_id):
+            batch = self._eval_data_fn()
+            metrics = {k: float(v)
+                       for k, v in self._eval_fn(params, batch).items()}
         return EvalReport(
             learner_id=self.learner_id,
             round_id=round_id,

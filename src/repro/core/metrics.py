@@ -13,6 +13,17 @@ through ``controller.telemetry``:
   round id).
 * :class:`Histogram` — streaming summaries (count/sum/min/max/last) of
   per-event observations (per-round wall-clock, aggregation seconds).
+* :class:`Span` — a timed block (``with telemetry.span(name, **ids):``).
+  It folds its seconds into the histogram registered under ``name`` and
+  opens a ``jax.profiler.TraceAnnotation`` of the same name, so the block
+  also lands in a profiler trace, on the device trace's clock and on the
+  line of the thread that opened it, with ``ids`` (``round``, ``learner``,
+  ``kind``) as event stats.  Spans are always on: with no profiler session
+  the annotation costs about a microsecond.  A span never synchronises
+  with the device; where its body only enqueues device work, it measures
+  the enqueue.  Spans opened inside one another on a thread nest, and the
+  enclosing span is the parent.  The catalogue of the program's spans is
+  in ``docs/OBSERVABILITY.md`` ("Spans").
 
 ``snapshot()`` renders the whole registry as one JSON-able dict — the same
 payload feeds the event journal's records (``core/journal.py``), the nightly
@@ -29,8 +40,11 @@ can bump counters concurrently with a ``snapshot()`` reader.
 from __future__ import annotations
 
 import threading
+import time
 
-__all__ = ["Counter", "Gauge", "Histogram", "Telemetry"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Counter", "Gauge", "Histogram", "Span", "Telemetry"]
 
 
 class Counter:
@@ -138,6 +152,42 @@ class Histogram:
                     "max": self.max, "last": self.last}
 
 
+class Span:
+    """A timed block: one histogram observation and one profiler trace event.
+
+    Made by :meth:`Telemetry.span`.  ``seconds`` is ``None`` until the block
+    exits, then holds its wall-clock length, so a caller that keeps its own
+    field (``RoundTimings.aggregation_s``) reads it from the span.  ``start``
+    is an earlier ``time.perf_counter()`` reading, possibly taken on another
+    thread: the seconds then count from it, while the trace event covers
+    only the part of the wait spent on this thread.  The block's time is
+    recorded whether it returns or raises.
+    """
+
+    __slots__ = ("_histogram", "_annotation", "_start", "seconds")
+
+    def __init__(self, histogram: Histogram, name: str,
+                 start: float | None, ids: dict):
+        self._histogram = histogram
+        self._annotation = TraceAnnotation(
+            name, **{k: v for k, v in ids.items() if v is not None}
+        )
+        self._start = start
+        self.seconds: float | None = None
+
+    def __enter__(self) -> "Span":
+        self._annotation.__enter__()
+        if self._start is None:
+            self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.seconds = time.perf_counter() - self._start
+        self._annotation.__exit__(*exc)
+        self._histogram.observe(self.seconds)
+        return False
+
+
 class Telemetry:
     """The instrument registry — one per federation (``controller.telemetry``).
 
@@ -174,6 +224,15 @@ class Telemetry:
     def histogram(self, name: str, help: str = "") -> Histogram:
         """Get or create the :class:`Histogram` registered under ``name``."""
         return self._get_or_create(Histogram, name, help)
+
+    def span(self, name: str, start: float | None = None, **ids) -> Span:
+        """A :class:`Span` over the block it guards, timed into ``name``.
+
+        ``ids`` (``round=``, ``learner=``, ``kind=``; ``None`` values are
+        left out) become the trace event's stats, so one task's spans on a
+        worker thread and on the engine loop can be matched up.
+        """
+        return Span(self.histogram(name), name, start, ids)
 
     def get(self, name: str):
         """The instrument registered under ``name`` (None if absent)."""

@@ -42,7 +42,6 @@ import dataclasses
 import functools
 import json
 import threading
-import time
 from typing import Any
 
 import jax
@@ -755,14 +754,24 @@ class Channel:
             self._c["serializations"].add(1)
             self._c["serialize_s"].add(dt)
 
+    def _span(self, name: str, metadata: dict | None):
+        """The channel's span ``name``, carrying the message's round and learner."""
+        md = metadata or {}
+        return self.telemetry.span(
+            name, round=md.get("round_id"), learner=md.get("learner_id")
+        )
+
     # -- send halves --------------------------------------------------------
     def send(self, params: Any, metadata: dict | None = None) -> Envelope:
-        """Serialize a pytree for one recipient (the legacy per-send half)."""
-        t0 = time.perf_counter()
-        if self.codec is not None:
-            params = self.codec.encode(params)
-        buf, manifest = packing.pack_bytes(params)
-        self._account_serialize(time.perf_counter() - t0)
+        """Serialize a pytree for one recipient (the legacy per-send half).
+
+        Timed by the ``channel.send`` span into ``serialize_s``.
+        """
+        with self._span("channel.send", metadata) as span:
+            if self.codec is not None:
+                params = self.codec.encode(params)
+            buf, manifest = packing.pack_bytes(params)
+        self._account_serialize(span.seconds)
         self._account_send(int(buf.nbytes))
         return Envelope(buffer=buf, manifest=manifest, metadata=dict(metadata or {}))
 
@@ -784,28 +793,35 @@ class Channel:
         is applied to ``params``) — still exactly one serialization.
 
         Per-recipient byte/wire-time accounting happens at each
-        :meth:`Broadcast.to`; this call accounts only the serialization.
+        :meth:`Broadcast.to`; this call accounts only the serialization,
+        timed by the ``channel.broadcast`` span (it waits for the device
+        to hand over the bytes).
         """
-        t0 = time.perf_counter()
-        if buffer is not None and manifest is not None and self.codec is None:
-            wire = packing.pack_bytes_from_numeric(buffer, manifest)
-            m = manifest
-        else:
-            src = params if self.codec is None else self.codec.encode(params)
-            wire, m = packing.pack_bytes(src)
-        self._account_serialize(time.perf_counter() - t0)
+        with self._span("channel.broadcast", metadata) as span:
+            if (buffer is not None and manifest is not None
+                    and self.codec is None):
+                wire = packing.pack_bytes_from_numeric(buffer, manifest)
+                m = manifest
+            else:
+                src = params if self.codec is None else self.codec.encode(params)
+                wire, m = packing.pack_bytes(src)
+        self._account_serialize(span.seconds)
         return Broadcast(self, wire, m, dict(metadata or {}))
 
     # -- receive ------------------------------------------------------------
     def recv(self, envelope: Envelope) -> Any:
-        """Deserialize at the receiver half."""
-        t0 = time.perf_counter()
-        params = packing.unpack_bytes(envelope.buffer, envelope.manifest)
-        if self.codec is not None:
-            params = self.codec.decode(params)
-        dt = time.perf_counter() - t0
+        """Deserialize at the receiver half.
+
+        Timed by the ``channel.recv`` span into ``deserialize_s``: the
+        host-to-device transfer of the payload and the enqueue of its
+        decode, as far as they block the receiving thread.
+        """
+        with self._span("channel.recv", envelope.metadata) as span:
+            params = packing.unpack_bytes(envelope.buffer, envelope.manifest)
+            if self.codec is not None:
+                params = self.codec.decode(params)
         with self._stats_lock:
-            self._c["deserialize_s"].add(dt)
+            self._c["deserialize_s"].add(span.seconds)
         return params
 
     # -- upload half (learner -> controller) --------------------------------
@@ -839,12 +855,14 @@ class Channel:
         payload's actual size (variable-length codecs like ``topk`` differ
         per upload when k clamps at tiny buffers) and ``upload_meta_bytes``
         the serialized envelope header; virtual wire time covers both.
+        The encode, the row's device-to-host copy included, is timed by the
+        ``channel.upload`` span into ``upload_serialize_s``.
         """
         c = self.upload_codec if codec is None else get_upload_codec(codec)
         n = int(np.shape(buffer)[0])
-        t0 = time.perf_counter()
-        payload = c.encode(buffer)
-        dt = time.perf_counter() - t0
+        with self._span("channel.upload", metadata) as span:
+            payload = c.encode(buffer)
+        dt = span.seconds
         payload.flags.writeable = False  # wire bytes are immutable
         envelope = UploadEnvelope(
             codec=c.codec_id, payload=payload, num_elements=n,
@@ -880,23 +898,26 @@ class Channel:
         the decode program — the admission screen's non-blocking readback.
         Registry codecs compute it in their one decode program; a custom codec
         without ``decode_with_norm`` pays one extra enqueued jit, still with
-        zero host syncs.
+        zero host syncs.  The decode is timed by the ``channel.recv_upload``
+        span into ``upload_deserialize_s`` (the transfer and the enqueue).
         """
         c = self._resolve_upload_codec(envelope)
-        t0 = time.perf_counter()
-        if with_norm:
-            fused = getattr(c, "decode_with_norm", None)
-            if fused is not None:
-                row, norm = fused(envelope.payload, envelope.num_elements)
+        with self._span("channel.recv_upload", envelope.metadata) as span:
+            if with_norm:
+                fused = getattr(c, "decode_with_norm", None)
+                if fused is not None:
+                    row, norm = fused(envelope.payload, envelope.num_elements)
+                else:
+                    row = c.decode(envelope.payload, envelope.num_elements)
+                    norm = _row_norm(row)
             else:
                 row = c.decode(envelope.payload, envelope.num_elements)
-                norm = _row_norm(row)
-        else:
-            row = c.decode(envelope.payload, envelope.num_elements)
-        dt = time.perf_counter() - t0
+        self._account_upload_decode(span.seconds)
+        return (row, norm) if with_norm else row
+
+    def _account_upload_decode(self, dt: float) -> None:
         with self._stats_lock:
             self._c["upload_deserialize_s"].add(dt)
-        return (row, norm) if with_norm else row
 
     def recv_upload_quantized(
         self, envelope: UploadEnvelope, out_params: int
@@ -918,13 +939,11 @@ class Channel:
                 f"codec {envelope.codec!r} cannot land quantized rows; "
                 "use recv_upload for f32 decode"
             )
-        t0 = time.perf_counter()
-        q, scales, norm = decode_q(
-            envelope.payload, envelope.num_elements, out_params
-        )
-        dt = time.perf_counter() - t0
-        with self._stats_lock:
-            self._c["upload_deserialize_s"].add(dt)
+        with self._span("channel.recv_upload", envelope.metadata) as span:
+            q, scales, norm = decode_q(
+                envelope.payload, envelope.num_elements, out_params
+            )
+        self._account_upload_decode(span.seconds)
         return q, scales, norm
 
     def recv_upload_sparse(
@@ -948,9 +967,7 @@ class Channel:
                 f"codec {envelope.codec!r} cannot land sparse rows; "
                 "use recv_upload for dense decode"
             )
-        t0 = time.perf_counter()
-        idx, val, norm = decode_s(envelope.payload, envelope.num_elements)
-        dt = time.perf_counter() - t0
-        with self._stats_lock:
-            self._c["upload_deserialize_s"].add(dt)
+        with self._span("channel.recv_upload", envelope.metadata) as span:
+            idx, val, norm = decode_s(envelope.payload, envelope.num_elements)
+        self._account_upload_decode(span.seconds)
         return idx, val, norm

@@ -12,7 +12,9 @@ Pins the tentpole's metrics contract:
   ``tests/test_dispatch.py`` asserts on the shims.
 """
 
+import functools
 import json
+import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -239,3 +241,162 @@ def test_engine_telemetry_survives_mock_controller():
     assert isinstance(eng.telemetry, Telemetry)
     eng.telemetry.counter("x").add(1)
     eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# spans: one timing mechanism, in the registry and in the profiler's trace
+# ---------------------------------------------------------------------------
+
+
+def test_span_folds_into_its_histogram_nests_and_exposes_seconds():
+    t = Telemetry()
+    with t.span("outer", round=1) as outer:
+        assert outer.seconds is None
+        with t.span("inner", round=1, learner="l0") as first:
+            time.sleep(0.01)
+        with t.span("inner", learner=None) as second:
+            pass
+    assert first.seconds >= 0.01
+    assert outer.seconds >= first.seconds + second.seconds
+    inner = t.get("inner")
+    assert isinstance(inner, Histogram)
+    assert inner.count == 2
+    assert inner.sum == pytest.approx(first.seconds + second.seconds)
+    assert t.snapshot()["outer"]["sum"] == outer.seconds
+    # A body that raises is timed too; the error passes through.
+    with pytest.raises(KeyError):
+        with t.span("outer"):
+            raise KeyError("x")
+    assert t.get("outer").count == 2
+    # ``start`` counts from an earlier reading, taken on any thread.
+    t0 = time.perf_counter()
+    time.sleep(0.01)
+    with t.span("wait", start=t0) as wait:
+        pass
+    assert wait.seconds >= 0.01 and t.get("wait").sum == wait.seconds
+
+
+def test_spans_from_many_threads_lose_no_observation():
+    import sys
+    import threading
+
+    t = Telemetry()
+    threads, per = 16, 500
+
+    def work(i):
+        for _ in range(per):
+            with t.span("engine.task.wait", learner=f"l{i}"):
+                pass
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        workers = [threading.Thread(target=work, args=(i,)) for i in range(threads)]
+        for w in workers:
+            w.start()
+        for w in workers:
+            w.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(w.is_alive() for w in workers)
+    assert t.get("engine.task.wait").count == threads * per
+
+
+def test_span_lands_in_a_profiler_trace_on_its_own_thread(tmp_path):
+    import glob
+    import threading
+
+    import jax
+
+    t = Telemetry()
+
+    def work():
+        with t.span("learner.fit", round=3, learner="l1"):
+            with t.span("learner.steps", round=3, learner="l1"):
+                pass
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        worker = threading.Thread(target=work)
+        worker.start()
+        worker.join()
+        with t.span("engine.wait", round=3):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)[0]
+    found = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for index, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name in ("learner.fit", "learner.steps", "engine.wait"):
+                    found[ev.name] = ((plane.name, index), dict(ev.stats),
+                                      ev.start_ns, ev.end_ns)
+    assert set(found) == {"learner.fit", "learner.steps", "engine.wait"}
+    fit, steps, wait = found["learner.fit"], found["learner.steps"], found["engine.wait"]
+    assert fit[1] == steps[1] == {"round": 3, "learner": "l1"}
+    assert wait[1] == {"round": 3}
+    # The nested span sits inside its parent on the worker's line; the
+    # loop's span is on a line of its own.
+    assert fit[0] == steps[0] != wait[0]
+    assert fit[2] <= steps[2] and steps[3] <= fit[3]
+
+
+def test_a_round_records_each_span_and_feeds_the_timers():
+    n = 2
+    ctrl = Controller(protocol=SyncProtocol(local_steps=2, batch_size=8))
+    ctrl.set_initial_model({"w": jnp.zeros((4, 1), jnp.float32)})
+    updates = []
+    for i in range(n):
+        learner = _make_learner(i)
+        fit = learner.fit
+
+        def spy(params, task, fit=fit):
+            updates.append(fit(params, task))
+            return updates[-1]
+
+        learner.fit = spy
+        ctrl.register_learner(learner)
+    (timings,) = ctrl.engine.run(rounds=1)
+    ctrl.shutdown()
+
+    tm = ctrl.telemetry
+    counts = {name: tm.get(name).count for name in tm.names()
+              if isinstance(tm.get(name), Histogram) and "." in name
+              and not name.endswith("_s")}
+    assert counts == {
+        "channel.broadcast": 2,           # the round's model, then the new one
+        "channel.recv": 2 * n,            # train and eval tasks
+        "channel.upload": n,
+        "channel.recv_upload": n,
+        "controller.commit": 1,
+        "controller.ingest": n,
+        "controller.reduce": 1,
+        "controller.screen": n,
+        "controller.write": n,
+        "engine.aggregate": 1,
+        "engine.dispatch": 2,             # train, then eval
+        "engine.evaluate": 1,
+        "engine.task.wait": 2 * n,        # n per phase
+        "engine.wait": n,                 # one per arrival
+        "learner.evaluate": n,
+        "learner.fit": n,
+        "learner.pack": n,
+        "learner.steps": n,
+    }
+
+    def total(name):
+        return tm.get(name).sum
+
+    approx = functools.partial(pytest.approx, rel=1e-9)
+    assert tm.value("channel.serialize_s") == approx(total("channel.broadcast"))
+    assert tm.value("channel.deserialize_s") == approx(total("channel.recv"))
+    assert tm.value("channel.upload_serialize_s") == approx(total("channel.upload"))
+    assert tm.value("channel.upload_deserialize_s") == approx(
+        total("channel.recv_upload"))
+    assert timings.train_dispatch_s + timings.eval_dispatch_s == approx(
+        total("engine.dispatch"))
+    assert timings.aggregation_s == approx(total("engine.aggregate"))
+    assert timings.eval_round_s == approx(total("engine.evaluate"))
+    assert sum(u.seconds_per_step * 2 for u in updates) == approx(
+        total("learner.steps"))
