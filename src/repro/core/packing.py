@@ -24,7 +24,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Sequence
+import itertools
+import math
+from typing import Any, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +41,8 @@ __all__ = [
     "pack_bytes",
     "pack_bytes_from_numeric",
     "unpack_bytes",
+    "WireRun",
+    "wire_runs",
     "pack_row_bytes",
     "unpack_row_bytes",
     "num_params",
@@ -286,21 +290,63 @@ def unpack_row_bytes(wire: np.ndarray, num_elements: int, dtype: Any = "float32"
     return jax.device_put(wire_view(wire, 0, int(num_elements), dt))
 
 
-def unpack_bytes(buffer: np.ndarray, manifest: Manifest) -> Any:
-    """Inverse of :func:`pack_bytes`: host views of every tensor, then
-    **one** batched ``device_put`` of the whole tree.
+class WireRun(NamedTuple):
+    """Consecutive wire leaves of one dtype: contiguous bytes, one transfer."""
 
-    Each leaf is a zero-copy view into the wire buffer (:func:`wire_view`),
-    so a receiver's deserialization cost is the O(P) transfer itself, with
-    no per-leaf host copy and no decode program to compile.
+    start: int  # byte offset of the run in the wire buffer
+    count: int  # elements in the run
+    dtype: str  # the leaves' dtype; a bool run moves as uint8
+    shapes: tuple[tuple[int, ...], ...]  # the run's leaves, in wire order
+
+
+@functools.lru_cache(maxsize=64)
+def wire_runs(manifest: Manifest) -> tuple[WireRun, ...]:
+    """The manifest's leaves grouped into runs of one dtype, in wire order.
+
+    Cached by the manifest's value, so every model version and every
+    recipient of one model structure reads the same runs.
     """
+    runs = []
+    start = 0
+    for dtype, group in itertools.groupby(manifest.specs, key=lambda s: s.dtype):
+        specs = tuple(group)
+        runs.append(WireRun(start, sum(s.size for s in specs), dtype,
+                            tuple(s.shape for s in specs)))
+        start += sum(s.nbytes for s in specs)
+    return tuple(runs)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _split_runs(arrays: list[jax.Array], runs: tuple[WireRun, ...]) -> list[jax.Array]:
+    """Every leaf of the ``(count,)`` run arrays: static slices and reshapes."""
     leaves = []
-    cursor = 0
-    for spec in manifest.specs:
-        if jnp.dtype(spec.dtype) == jnp.dtype(bool):
-            leaf = wire_view(buffer, cursor, spec.size, np.uint8).astype(bool)
-        else:
-            leaf = wire_view(buffer, cursor, spec.size, spec.dtype)
-        leaves.append(leaf.reshape(spec.shape))
-        cursor += spec.nbytes
-    return jax.tree_util.tree_unflatten(manifest.treedef, jax.device_put(leaves))
+    for array, run in zip(arrays, runs):
+        start = 0
+        for shape in run.shapes:
+            size = math.prod(shape)
+            leaf = jax.lax.slice(array, (start,), (start + size,)).reshape(shape)
+            leaves.append(leaf != 0 if run.dtype == "bool" else leaf)
+            start += size
+    return leaves
+
+
+def unpack_bytes(buffer: np.ndarray, manifest: Manifest) -> Any:
+    """Inverse of :func:`pack_bytes`: one ``device_put`` per dtype run, then
+    **one** jitted split into the tree's leaves.
+
+    Consecutive leaves of one dtype (:func:`wire_runs`) are contiguous on the
+    wire, so each run is one zero-copy host view (:func:`wire_view`) moved
+    as a ``(count,)`` array of its dtype; bool runs move as ``uint8`` and
+    compare ``!= 0`` in the split.  The split program is compiled once per
+    run layout, so a new model version of the same structure, and every
+    other recipient, reuses it.  A float32 model is one transfer and one
+    split dispatch, however many leaves it has.
+    """
+    runs = wire_runs(manifest)
+    # The run arrays are not kept: the second copy of the model lives only
+    # while the split runs.
+    leaves = _split_runs(jax.device_put([
+        wire_view(buffer, r.start, r.count, np.uint8 if r.dtype == "bool" else r.dtype)
+        for r in runs
+    ]), runs)
+    return jax.tree_util.tree_unflatten(manifest.treedef, leaves)

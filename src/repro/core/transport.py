@@ -61,9 +61,9 @@ __all__ = [
 #: The channel's telemetry counter names (registered as ``channel.<field>``).
 _STAT_FIELDS = (
     "messages", "bytes_moved", "serializations", "serialize_s",
-    "deserialize_s", "virtual_wire_s", "upload_messages", "upload_bytes",
-    "upload_meta_bytes", "upload_serializations", "upload_serialize_s",
-    "upload_deserialize_s", "upload_virtual_wire_s",
+    "deserialize_s", "recv_transfers", "virtual_wire_s", "upload_messages",
+    "upload_bytes", "upload_meta_bytes", "upload_serializations",
+    "upload_serialize_s", "upload_deserialize_s", "upload_virtual_wire_s",
 )
 
 
@@ -812,9 +812,12 @@ class Channel:
     def recv(self, envelope: Envelope) -> Any:
         """Deserialize at the receiver half.
 
-        Timed by the ``channel.recv`` span into ``deserialize_s``: the
-        host-to-device transfer of the payload and the enqueue of its
-        decode, as far as they block the receiving thread.
+        ``packing.unpack_bytes`` moves each run of same-dtype leaves with one
+        host-to-device transfer and splits them into the tree with one
+        cached program; ``recv_transfers`` counts the transfers (1 for a
+        dtype-homogeneous model).  Timed by the ``channel.recv`` span into
+        ``deserialize_s``: the transfers and the enqueue of the split and of
+        the codec's decode, as far as they block the receiving thread.
         """
         with self._span("channel.recv", envelope.metadata) as span:
             params = packing.unpack_bytes(envelope.buffer, envelope.manifest)
@@ -822,6 +825,7 @@ class Channel:
                 params = self.codec.decode(params)
         with self._stats_lock:
             self._c["deserialize_s"].add(span.seconds)
+            self._c["recv_transfers"].add(len(packing.wire_runs(envelope.manifest)))
         return params
 
     # -- upload half (learner -> controller) --------------------------------

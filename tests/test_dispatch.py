@@ -122,6 +122,47 @@ def test_broadcast_falls_back_to_pytree_once_with_codec():
         )
 
 
+def test_recv_compiles_one_split_for_equal_manifests_of_two_versions():
+    """Two model versions whose manifests are equal but not the same object
+    decode through one compiled split program."""
+    v1 = {"a": jnp.arange(91, dtype=jnp.float32).reshape(7, 13),
+          "b": jnp.full((13,), 0.5, jnp.float32)}
+    v2 = {k: v * 3 + 1 for k, v in v1.items()}
+    m1, m2 = packing.build_manifest(v1), packing.build_manifest(v2)
+    assert m1 == m2 and m1 is not m2
+    ch = Channel()
+    before = packing._split_runs._cache_size()
+    for version, m in ((v1, m1), (v2, m2)):
+        bc = ch.broadcast(buffer=packing.pack_numeric(version), manifest=m)
+        for _ in range(2):
+            got = ch.recv(bc.to())
+            for k in version:
+                assert np.asarray(got[k]).tobytes() == np.asarray(version[k]).tobytes()
+    assert packing._split_runs._cache_size() == before + 1
+
+
+@pytest.mark.parametrize("tree_fn, codec, runs", [
+    (lambda: {f"w{i:03d}": jnp.full((i % 5 + 1, 3), i, jnp.float32)
+              for i in range(40)}, None, 1),
+    (lambda: [jnp.ones((4,), jnp.float32), jnp.ones((3,), jnp.bfloat16),
+              jnp.ones((2,), jnp.float32), jnp.zeros((5,), bool)], None, 4),
+    (lambda: {"w": jnp.linspace(-1, 1, 64, dtype=jnp.float32)}, "int8", 3),
+], ids=["f32", "mixed", "quantized"])
+def test_recv_transfers_counts_one_per_dtype_run(tree_fn, codec, runs):
+    """``channel.recv_transfers`` adds one per dtype run at every recv: 1 for
+    a homogeneous tree, however many leaves; the int8 downlink codec's
+    int32 / int8 / float32 leaves are 3."""
+    from repro.kernels.ops import QuantCodec
+
+    ch = Channel(quantize_codec=QuantCodec() if codec else None)
+    env = ch.send(tree_fn())
+    assert len(packing.wire_runs(env.manifest)) == runs
+    for _ in range(3):
+        ch.recv(env)
+    assert ch.telemetry.value("channel.recv_transfers") == 3 * runs
+    assert ch.stats.recv_transfers == 3 * runs
+
+
 def test_pack_bytes_from_numeric_bit_identical_and_pad_oblivious():
     tree = _mixed_tree()
     manifest = packing.build_manifest(tree)
@@ -163,6 +204,7 @@ def test_channel_stats_threadsafe_under_16_thread_hammer():
     assert ch.stats.messages == 2 * total  # one send + one broadcast.to each
     assert ch.stats.bytes_moved == 2 * total * nbytes
     assert ch.stats.serializations == total + 1  # sends + the one broadcast
+    assert ch.stats.recv_transfers == total  # one float32 run per recv
     assert bc.recipients == total
     # uplink half: every upload is its own message AND serialization
     assert ch.stats.upload_messages == total

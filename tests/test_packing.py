@@ -61,6 +61,68 @@ def test_bytes_roundtrip_bitexact(tree):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
+def _per_leaf_unpack(buffer, manifest):
+    """The per-leaf decode ``unpack_bytes`` replaces: one transfer per leaf."""
+    leaves = []
+    cursor = 0
+    for spec in manifest.specs:
+        if spec.dtype == "bool":
+            leaf = packing.wire_view(buffer, cursor, spec.size, np.uint8).astype(bool)
+        else:
+            leaf = packing.wire_view(buffer, cursor, spec.size, spec.dtype)
+        leaves.append(jax.device_put(leaf.reshape(spec.shape)))
+        cursor += spec.nbytes
+    return jax.tree_util.tree_unflatten(manifest.treedef, leaves)
+
+
+def _bits(shape, dtype, seed):
+    """Random bit patterns of ``dtype``: NaN payloads, -0.0 and subnormals too."""
+    dt = np.dtype(jnp.dtype(dtype))
+    size = int(np.prod(shape))
+    raw = np.random.default_rng(seed).integers(0, 256, size * dt.itemsize, np.uint8)
+    if dt == np.bool_:
+        return (raw % 2).astype(bool).reshape(shape)
+    return raw.view(dt).reshape(shape)
+
+
+_UNPACK_TREES = {
+    "f32-200-leaves": lambda: {
+        f"layer_{i:03d}": _bits((i % 7 + 1, 3 + i % 5), np.float32, i) for i in range(200)
+    },
+    "alternating-dtypes": lambda: [
+        _bits(shape, dtype, i)
+        for i, (shape, dtype) in enumerate(
+            [((4, 3), np.float32), ((5,), jnp.bfloat16), ((7,), np.int8), ((3,), bool)] * 3
+        )
+    ],
+    "scalar-and-zero-size": lambda: {
+        "a": _bits((), np.float32, 1), "b": np.zeros((0, 3), np.float32),
+        "c": _bits((), np.int32, 2), "d": np.zeros((0,), bool),
+        "e": _bits((2, 0, 4), jnp.bfloat16, 3), "f": _bits((6,), np.float32, 4),
+    },
+    "single-leaf": lambda: _bits((33, 17), np.float32, 5),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_UNPACK_TREES))
+def test_unpack_bytes_matches_per_leaf_decode(name):
+    """One transfer per dtype run and one split program decode the same
+    tree, bit for bit, as one transfer per leaf."""
+    tree = _UNPACK_TREES[name]()
+    buf, m = packing.pack_bytes(tree)
+    got, want = packing.unpack_bytes(buf, m), _per_leaf_unpack(buf, m)
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(tree)
+    for a, b, spec in zip(jax.tree_util.tree_leaves(got),
+                          jax.tree_util.tree_leaves(want), m.specs):
+        assert isinstance(a, jax.Array)
+        assert a.dtype == b.dtype == jnp.dtype(spec.dtype)
+        assert a.shape == b.shape == spec.shape
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    runs = packing.wire_runs(m)
+    assert sum(len(r.shapes) for r in runs) == len(m.specs)
+    assert all(a.dtype != b.dtype for a, b in zip(runs, runs[1:]))
+
+
 @settings(max_examples=30, deadline=None)
 @given(pytrees())
 def test_pack_bytes_from_numeric_matches_pytree_pack(tree):
