@@ -16,6 +16,15 @@ from bench import counts
 
 learner_flops = counts.lm_learner_flops
 
+#: A CPU test's stand-in of a configuration of this family: the keys it
+#: shrinks in the configuration, the traffic and the traffic's federation.
+TINY = {
+    "config": dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
+                   num_key_value_heads=2, num_hidden_layers=2, vocab_size=512),
+    "traffic": dict(seq_len=32),
+    "federation": dict(local_steps=2),
+}
+
 
 def program_model(config: dict):
     from repro.models.config import ModelConfig

@@ -19,6 +19,10 @@ from bench import counts, spec
 
 learner_flops = counts.mlp_learner_flops
 
+#: A CPU test's stand-in of a configuration of this family: the keys it shrinks
+#: in the configuration, the traffic and the traffic's federation.
+TINY = {"config": dict(n_hidden_layers=4, width=32)}
+
 
 def program_model(config: dict):
     from repro.configs.housing_mlp import MLPConfig

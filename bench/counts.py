@@ -97,14 +97,20 @@ def roofline_s(cost: dict, peaks: dict) -> float:
 
 @dataclasses.dataclass(frozen=True)
 class Shapes:
-    """The arena's shape as the run built it."""
+    """The arena's shape as the run built it: ``width`` columns, split
+    into ``shards`` equal column shards, one a device."""
 
     rows: int
     width: int
     arena_dtype: str
+    shards: int = 1
+
+    @property
+    def shard_width(self) -> int:
+        return self.width // self.shards
 
     @classmethod
     def of(cls, fed) -> "Shapes":
         arena = fed.controller.arena
         return cls(rows=int(arena.buffer.shape[0]), width=int(arena.padded_params),
-                   arena_dtype=str(arena.arena_dtype))
+                   arena_dtype=str(arena.arena_dtype), shards=int(arena.n_shards))

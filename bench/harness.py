@@ -14,6 +14,9 @@ change would remove them):
 Set-up (``setup_s``) is everything before the window: weights on the device,
 learners, the driver, and the warm-up rounds, the first of which compiles.
 The first ``reference_rounds`` of them are what the comparison follows.
+The window (:func:`measure`) is framed by two snapshots of the program's
+``Telemetry`` registry, taken on the host between rounds, which the
+per-layer readers read through ``bench/spans.py``.
 """
 
 from __future__ import annotations
@@ -215,9 +218,17 @@ def configure_jax() -> str:
     return cache
 
 
-def run(w: spec.Workload, seed: int, seconds: float, trace: bool, *,
-        require_chip: bool = True, plant=None, log=print) -> dict:
-    """One run of cell ``w``; returns the result line's object."""
+def measure(w: spec.Workload, seed: int, seconds: float, trace: bool, *,
+            require_chip: bool = True, plant=None, log=print):
+    """Set-up, warm-up and the window of one run of cell ``w``.
+
+    Returns the readers' :class:`Context` and the federation's readings of
+    its first rounds.  The program's ``Telemetry`` registry is snapshotted
+    on the host right before the window and right after it (``ctx.before``,
+    ``ctx.after``); with ``trace`` the window runs under the profiler and
+    ``ctx.planes`` / ``ctx.trace`` hold the trace.  The federation is closed
+    before this returns, so the reference replay has the chip to itself.
+    """
     t_start = time.perf_counter()
     if require_chip:
         configure_jax()
@@ -244,12 +255,14 @@ def run(w: spec.Workload, seed: int, seconds: float, trace: bool, *,
     log(f"setup {setup_s:.2f} s, {setup}")
 
     spans.reset()
+    telemetry = fed.controller.telemetry
     tracer = trace_mod.Tracer() if trace else contextlib.nullcontext()
+    before = telemetry.snapshot()
     with tracer:
         timings, attempted, failed, window_s = _window(fed, seconds)
+    after = telemetry.snapshot()
     in_window = compiles.since(setup)
-    memory_peak = device.memory_peak_bytes()
-    dev["memory_peak_bytes"] = memory_peak
+    dev["memory_peak_bytes"] = device.memory_peak_bytes()
     log(f"window {window_s:.3f} s, {len(timings)} rounds, {in_window}")
 
     shapes = counts.Shapes.of(fed)
@@ -257,35 +270,58 @@ def run(w: spec.Workload, seed: int, seconds: float, trace: bool, *,
     del fed
     gc.collect()
 
+    planes = tracer.planes() if trace else None
+    ctx = Context(w=w, dev=dev, timings=timings, window_s=window_s, spans=spans,
+                  setup_compile=setup, shapes=shapes, before=before, after=after,
+                  planes=planes, trace=None if planes is None else trace_mod.reduce(planes),
+                  setup_s=setup_s, attempted=attempted, failed=failed,
+                  window_compiles=in_window["compiles"])
+    return ctx, prog
+
+
+def run(w: spec.Workload, seed: int, seconds: float, trace: bool, *,
+        require_chip: bool = True, plant=None, log=print) -> dict:
+    """One run of cell ``w``; returns the result line's object."""
+    ctx, prog = measure(w, seed, seconds, trace, require_chip=require_chip,
+                        plant=plant, log=log)
+    ctx.planes = None  # reduced into ctx.trace; freed before the replay
+
     ref = reference_readings(w, seed)
     values = check.numbers(prog, ref)
     ok, shown = check.verdict(values, w.limits)
-    ok = ok and failed == 0
+    ok = ok and ctx.failed == 0
 
-    ctx = Context(w=w, dev=dev, timings=timings, window_s=window_s, spans=spans,
-                  setup_compile=setup, shapes=shapes, trace=None)
-    result: dict = {"correct": ok, "attempted": attempted, "failed": failed}
+    dev = ctx.dev
+    result: dict = {"correct": ok, "attempted": ctx.attempted, "failed": ctx.failed}
     if trace:
-        ctx.trace = tracer.summary()
         dev["busy_s"] = ctx.trace.busy_s
         dev["window_s"] = ctx.trace.window_s
         result["metrics"] = per_layer(ctx)
         result["breakdown"] = ctx.trace.breakdown()
     else:
         result["metrics"] = {
-            "round_s": {"value": window_s / len(timings), "unit": "s"},
-            "setup_s": {"value": setup_s, "unit": "s"},
+            "round_s": {"value": ctx.window_s / len(ctx.timings), "unit": "s"},
+            "setup_s": {"value": ctx.setup_s, "unit": "s"},
         }
     result["device"] = dev
-    result["window"] = {"rounds": len(timings), "seconds": window_s,
-                        "compiles": in_window["compiles"]}
+    result["window"] = {"rounds": len(ctx.timings), "seconds": ctx.window_s,
+                        "compiles": ctx.window_compiles}
     result["readings"] = values
     result["check"] = shown
     return result
 
 
 class Context:
-    """What a per-layer metric reader may read."""
+    """What a per-layer metric reader may read.
+
+    Besides the window's ``timings``, ``spans``, ``shapes``, the set-up's
+    compiles and the trace ``Summary`` (None untraced): ``before`` and
+    ``after``, the program's ``Telemetry`` snapshots around the window, read
+    with ``bench.spans.window`` (span histograms) and ``bench.spans.delta``
+    (counters and gauges).  A reader names the program spans, counters and
+    compiled programs it reads in its module's ``SPANS``, ``COUNTERS`` and
+    ``PROGRAMS``, which the CPU tests fill with made-up readings.
+    """
 
     def __init__(self, **kw):
         self.__dict__.update(kw)

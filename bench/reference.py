@@ -31,6 +31,7 @@ the first half of its batch, the mean taken over it) or
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -98,7 +99,8 @@ class Plain:
         def flatten(p):
             return jnp.concatenate([l.reshape(-1) for l in jax.tree_util.tree_leaves(p)])
 
-        @jax.jit
+        # A float32 row is donated: the uplink's float32 output takes its buffer.
+        @functools.partial(jax.jit, donate_argnums=0 if dtype == jnp.float32 else ())
         def send(row):
             return transmit(row).astype(jnp.float32)
 
@@ -123,12 +125,21 @@ class Plain:
             p, val = self.sgd_step(p, b)
         return p, val
 
-    def upload(self, p, learner: int):
-        """The learner's flat row as the uplink delivers it, in float32."""
+    def update(self, base, learner: int, first: int):
+        """Local steps from the global row ``base`` on batches ``first..``.
+
+        Returns the learner's flat row as the uplink delivers it, in
+        float32, and its last loss.  The trained parameters are freed once
+        flattened, and the flat row once sent.  The row is waited for, so
+        that the host does not queue the next learner's steps, whose buffers
+        the device would then hold beside this learner's.
+        """
+        p, val = self.train(self.unflatten(base), learner, first)
         row = self.flatten(p)
+        del p
         if self.fault == "upload_altered" and learner == 0:
             row = row.at[0].add(1.0)
-        return self.send(row)
+        return jax.block_until_ready(self.send(row)), val
 
     def commit(self, base, fold):
         """The committed model from the global row and the round's fold."""

@@ -1,10 +1,17 @@
 #!/usr/bin/env python3
-"""The program's own spans over a traced window: six means and idle by thread.
+"""The program's own spans and counters over a window, and idle by thread.
 
     python bench/spans.py --workload <cell> --seed <n> --seconds <s>
 
-Runs one cell as ``bench/run.py --trace 1`` does (the same federation, the
-same warm-up, the same window under the profiler) and prints one JSON line:
+The harness (``bench/harness.measure``) takes a snapshot of the program's
+``Telemetry`` registry (``controller.telemetry``, which the channel and the
+registered learners report into) right before its window and right after
+it, on the host between rounds.  :func:`window` and :func:`delta` read two
+such snapshots; the per-layer readers in ``bench/metrics/`` call them.
+
+The CLI runs one cell's set-up, warm-up and window under the profiler
+through that same ``harness.measure`` (no reference replay, no
+``correct``) and prints one JSON line:
 
 * ``metrics``: the window's mean of one program span each, in ms (the
   ``Telemetry`` histograms' count and sum at the window's end less those at
@@ -17,23 +24,22 @@ same warm-up, the same window under the profiler) and prints one JSON line:
   the innermost program span open on that thread (:func:`idle_by_thread`),
   and the idle time under no program span on any thread.
 
-The same tables end standard error.  It decides no ``correct`` and the
-benchmark's own runs never run it.
+The same tables end standard error.  The benchmark's own runs never run it.
 """
 
 from __future__ import annotations
 
 import argparse
 import collections
-import glob
 import json
 import os
-import shutil
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-#: Per-layer metric name -> the program span whose window mean it reads.
+#: Name -> the program span whose window mean the CLI prints under it.  Each
+#: but ``upload_encode_ms`` (``upload_encode_gbps`` reads the same span's
+#: sum) is also a per-layer metric, whose reader names its span in ``SPANS``.
 METRICS = {
     "downlink_decode_ms": "channel.recv",
     "upload_encode_ms": "channel.upload",
@@ -59,6 +65,11 @@ def window(before: dict, after: dict) -> dict[str, tuple[int, float]]:
         if h["count"] > b["count"]:
             out[name] = (h["count"] - b["count"], h["sum"] - b["sum"])
     return out
+
+
+def delta(before: dict, after: dict, name: str) -> float | None:
+    """A counter's or gauge's change between two snapshots; None where it is absent."""
+    return after[name] - before.get(name, 0) if name in after else None
 
 
 def mean_ms(spans: dict, name: str) -> float | None:
@@ -140,45 +151,19 @@ def by_role(idle: dict) -> dict[str, dict]:
 
 
 def run(w, seed: int, seconds: float, *, require_chip: bool = True) -> dict:
-    """Cell ``w`` built, warmed up and traced over a window of ``seconds``."""
-    from bench import device, harness
-    from bench import trace as trace_mod
+    """Cell ``w`` built, warmed up and traced over the harness's window."""
+    from bench import harness
 
-    if require_chip:
-        harness.configure_jax()
-        dev = device.require_tpu(w.chips)
-    else:
-        dev = device.info()
-    spans = harness.Spans(annotate=True)
-    fed = harness.Federation(w, seed, spans)
-    try:
-        for _ in range(int(w.traffic["warmup_rounds"])):
-            fed.round()
-        fed.record_losses = False
-        spans.reset()
-        telemetry = fed.controller.telemetry
-        before = telemetry.snapshot()
-        tracer = trace_mod.Tracer()
-        try:
-            with tracer:
-                timings, attempted, failed, window_s = harness._window(fed, seconds)
-            after = telemetry.snapshot()
-            found = glob.glob(os.path.join(tracer.dir, "**", "*.xplane.pb"),
-                              recursive=True)
-            planes = trace_mod.read_planes(found[0])
-        finally:
-            shutil.rmtree(tracer.dir, ignore_errors=True)
-    finally:
-        fed.close()
-    deltas = window(before, after)
-    summary = trace_mod.reduce(planes)
-    idle = idle_by_thread(planes)
-    outside = {"fit_ms": spans.mean_s("fit"), "ingest_ms": spans.mean_s("ingest")}
+    ctx, _ = harness.measure(w, seed, seconds, True, require_chip=require_chip,
+                             log=lambda m: None)
+    deltas = window(ctx.before, ctx.after)
+    idle = idle_by_thread(ctx.planes)
+    outside = {"fit_ms": ctx.spans.mean_s("fit"), "ingest_ms": ctx.spans.mean_s("ingest")}
     return {
-        "workload": w.name, "seed": seed, "device": dev,
-        "rounds": len(timings), "attempted": attempted, "failed": failed,
-        "round_s": window_s / len(timings),
-        "window_s": summary.window_s, "busy_s": summary.busy_s,
+        "workload": w.name, "seed": seed, "device": ctx.dev,
+        "rounds": len(ctx.timings), "attempted": ctx.attempted, "failed": ctx.failed,
+        "round_s": ctx.window_s / len(ctx.timings),
+        "window_s": ctx.trace.window_s, "busy_s": ctx.trace.busy_s,
         "metrics": {k: mean_ms(deltas, v) for k, v in METRICS.items()},
         "check": {
             "learner.fit_ms": mean_ms(deltas, "learner.fit"),
@@ -187,12 +172,12 @@ def run(w, seed: int, seconds: float, *, require_chip: bool = True) -> dict:
             "ingest_ms": (None if outside["ingest_ms"] is None
                           else 1e3 * outside["ingest_ms"]),
             "engine.aggregate_s": deltas.get("engine.aggregate", (0, 0.0))[1],
-            "aggregation_s": sum(t.aggregation_s for t in timings),
+            "aggregation_s": sum(t.aggregation_s for t in ctx.timings),
         },
         "spans": {k: list(v) for k, v in sorted(deltas.items())},
         "idle": {"idle_s": idle["idle_s"], "no_span_s": idle["no_span_s"],
                  "threads": len(idle["threads"]), "by_role": by_role(idle)},
-        "breakdown": summary.breakdown(),
+        "breakdown": ctx.trace.breakdown(),
     }
 
 

@@ -4,8 +4,9 @@ Device activity is read from each TPU plane's ``XLA Modules`` line: one
 event per execution of a compiled program, named ``<program>(<id>)``.  Busy
 time is the union of those intervals inside the traced window, per device,
 averaged over the devices used.  An idle gap is a stretch of the window in
-which no program ran; its time is charged to the innermost benchmark span
-(``bench.*`` host annotations) open at each moment, latest-started first.
+which no program ran on a device; its time is charged to the innermost
+benchmark span (``bench.*`` host annotations) open at each moment,
+latest-started first, and averaged over the devices as busy time is.
 """
 
 from __future__ import annotations
@@ -56,7 +57,11 @@ def gaps(busy, lo: float, hi: float) -> list[tuple[float, float]]:
 
 
 def attribute(free, spans) -> collections.Counter:
-    """Charge each moment of ``free`` to the latest-started open span."""
+    """Charge each moment of ``free`` to the latest-started open span.
+
+    ``free`` may hold the gaps of several devices: a moment is charged once
+    for each device idle in it.
+    """
     events = []
     for s, e in free:
         events += [(s, 1, "gap", None), (e, 0, "gap", None)]
@@ -72,7 +77,7 @@ def attribute(free, spans) -> collections.Counter:
                 name = max(open_spans.values(), key=lambda v: v[1])[0]
             else:
                 name = NO_SPAN
-            charged[name] += t - last
+            charged[name] += (t - last) * in_gap
         if kind == "gap":
             in_gap += 1 if opening else -1
         elif opening:
@@ -176,7 +181,7 @@ def read_planes(path: str):
 
 
 class Tracer:
-    """Profile a window into a temporary directory, then reduce and delete it."""
+    """Profile a window into a temporary directory; its planes are read once, then it is deleted."""
 
     def __enter__(self):
         import jax
@@ -197,11 +202,12 @@ class Tracer:
         jax.profiler.stop_trace()
         return False
 
-    def summary(self) -> Summary:
+    def planes(self):
+        """The traced window's planes (:func:`read_planes`); the files are deleted."""
         try:
             found = glob.glob(os.path.join(self.dir, "**", "*.xplane.pb"), recursive=True)
             if not found:
                 raise ValueError("the profiler wrote no .xplane.pb")
-            return reduce(read_planes(found[0]))
+            return read_planes(found[0])
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)

@@ -10,7 +10,7 @@ import pytest
 
 import bench_tiny
 
-from bench import calibrate, check, harness
+from bench import calibrate, check
 
 
 @pytest.mark.parametrize("cell", bench_tiny.CELLS)
@@ -21,74 +21,23 @@ def test_control_fails_the_limits(cell):
     assert variant == "control" and not ok, shown
 
 
-def _unchanged(fed):
-    """The server step returns the global model as it was."""
-    fed.controller._commit = lambda new_buffer: None
+CASES = [(cell, fault) for cell in bench_tiny.CELLS for fault in bench_tiny.faults(cell)]
 
 
-def _half_batch(fed):
-    """Every learner's step sees half its batch: the mean over the rest."""
-    for learner in fed.controller._learners.values():
-        feed = learner._data_fn
-
-        def half(size, feed=feed):
-            import jax
-
-            return jax.tree_util.tree_map(lambda a: a[: a.shape[0] // 2], feed(size))
-
-        learner._data_fn = half
-
-
-def _upload_altered(fed):
-    """One learner's first uploaded value is off by 1.0 where it is produced."""
-    channel = fed.controller.channel
-    upload = channel.upload
-
-    def altered(buffer, metadata=None, codec=None):
-        if (metadata or {}).get("learner_id") == "learner_000":
-            buffer = buffer.at[0].add(1.0)
-        return upload(buffer, metadata=metadata, codec=codec)
-
-    channel.upload = altered
-
-
-FAULTS = {"unchanged": _unchanged, "half_batch": _half_batch,
-          "upload_altered": _upload_altered}
-
-
-@pytest.mark.parametrize("fault", sorted(FAULTS))
-@pytest.mark.parametrize("cell", bench_tiny.CELLS)
+@pytest.mark.parametrize("cell,fault", CASES, ids=[f"{c}-{f}" for c, f in CASES])
 def test_a_broken_timed_path_is_not_correct(cell, fault):
-    w = bench_tiny.workload(cell)
-    res = harness.run(w, 5, 0.1, False, require_chip=False, plant=FAULTS[fault],
-                      log=lambda m: None)
+    res = bench_tiny.run(cell, 5, 0.1, plant=fault)
     assert res["correct"] is False, res["check"]
 
 
-def _last_commit_left_out(fed):
-    """The last compared round's server step returns the model as it was."""
-    commit = fed.controller._commit
-    calls = []
-
-    def skip_last(new_buffer):
-        calls.append(new_buffer)
-        if len(calls) == int(fed.w.traffic["reference_rounds"]):
-            return None
-        return commit(new_buffer)
-
-    fed.controller._commit = skip_last
-
-
-@pytest.mark.parametrize("cell", bench_tiny.CELLS[1:])
+@pytest.mark.parametrize(
+    "cell", [c for c in bench_tiny.CELLS if bench_tiny.catches_a_left_out_commit(c)])
 def test_a_later_commit_left_out_is_not_correct(cell):
-    """The f32 MLP cell's limit sits above this fault; the cells sharing `_commit` catch it."""
-    w = bench_tiny.workload(cell)
-    res = harness.run(w, 5, 0.1, False, require_chip=False, plant=_last_commit_left_out,
-                      log=lambda m: None)
+    """A change_gap limit of 1 or more (the f32 MLP cell's) passes this fault; the rest catch it."""
+    res = bench_tiny.run(cell, 5, 0.1, plant="last_commit_left_out")
     assert res["correct"] is False, res["check"]
 
 
 def test_unbroken_runs_are_correct():
-    w = bench_tiny.workload(bench_tiny.CELLS[0])
-    res = harness.run(w, 5, 0.1, False, require_chip=False, log=lambda m: None)
+    res = bench_tiny.run(bench_tiny.pick(codec="raw"), 5, 0.1)
     assert res["correct"] is True, res["check"]

@@ -33,7 +33,7 @@ def test_a_part_that_is_not_there_is_a_spec_error():
 
 def test_federation_settings_reach_both_sides():
     """A server step of 0.5 in the traffic: the program and the reference both take it."""
-    w = bench_tiny.workload(bench_tiny.CELLS[0])
+    w = bench_tiny.workload(bench_tiny.pick())
     fed = dict(w.traffic["federation"], server_lr=0.5)
     w = dataclasses.replace(w, traffic=dict(w.traffic, federation=fed))
     res = harness.run(w, 9, 0.1, False, require_chip=False, log=lambda m: None)
@@ -47,7 +47,7 @@ def test_federation_settings_reach_both_sides():
 def test_the_quantized_control_halves_the_codecs_bits():
     import jax.numpy as jnp
 
-    w = bench_tiny.workload(bench_tiny.CELLS[2])
+    w = bench_tiny.workload(bench_tiny.pick(codec="int8"))
     uplink = calibrate.control_kw(w)["uplink"]
     row = jnp.linspace(-1.0, 1.0, 512, dtype=jnp.float32)
     got = np.asarray(uplink(row)).reshape(2, 256)
@@ -59,7 +59,7 @@ def test_the_quantized_control_halves_the_codecs_bits():
 
 def test_folding_rows_differs_from_folding_changes_only_in_rounding():
     """On the CPU's float32 matmuls the program's form of FedAvg agrees closely."""
-    w = bench_tiny.workload(bench_tiny.CELLS[0])
+    w = bench_tiny.workload(bench_tiny.pick(family="mlp", codec="raw"))
     (variant, values), = calibrate.readings(w, 4, variants=("rows",))
     assert variant == "rows"
     assert values["update_gap"] < 1e-4 and values["change_gap"] < 1e-3, values
@@ -72,7 +72,7 @@ def test_a_refused_draw_is_drawn_again_from_the_seeds_next_key():
 
     from bench import traffic
 
-    w = bench_tiny.workload(bench_tiny.CELLS[0])
+    w = bench_tiny.workload(bench_tiny.pick(family="mlp"))
     fam = spec.family(w.family)
     model = fam.program_model(w.config)
     source = traffic.make(w.config, w.traffic, 5)
@@ -99,7 +99,7 @@ def test_a_refused_draw_is_drawn_again_from_the_seeds_next_key():
 def test_the_mlp_check_refuses_a_draw_whose_federation_blows_up(lr, ok):
     from bench import traffic
 
-    w = bench_tiny.workload(bench_tiny.CELLS[0])
+    w = bench_tiny.workload(bench_tiny.pick(family="mlp"))
     config = dict(w.config, learning_rate=lr)
     fam = spec.family(w.family)
     source = traffic.make(config, w.traffic, 5)

@@ -62,7 +62,7 @@ def test_idle_by_thread_needs_the_window():
 
 
 def test_a_tiny_cell_reads_its_spans_over_the_window():
-    w = bench_tiny.workload(bench_tiny.CELLS[0])
+    w = bench_tiny.workload(bench_tiny.pick(codec="raw"))
     out = spans.run(w, 2**31 + 7, 0.3, require_chip=False)
     n = int(w.traffic["learners"]) * out["rounds"]
     assert out["rounds"] > 0 and out["failed"] == 0
@@ -77,3 +77,27 @@ def test_a_tiny_cell_reads_its_spans_over_the_window():
     # The program's spans and the benchmark's wrappers time the same calls.
     assert check["learner.fit_ms"] <= check["fit_ms"]
     assert check["controller.ingest_ms"] <= check["ingest_ms"]
+
+
+def test_a_counters_delta_is_its_change_and_none_where_absent():
+    before = {"channel.upload_bytes": 10, "store.arena.rows": 8,
+              "channel.recv": {"count": 1, "sum": 0.1}}
+    after = {"channel.upload_bytes": 30, "store.arena.rows": 16, "controller.late": 2.5,
+             "channel.recv": {"count": 3, "sum": 0.3}}
+    assert spans.delta(before, after, "channel.upload_bytes") == 20
+    assert spans.delta(before, after, "store.arena.rows") == 8  # a gauge
+    assert spans.delta(before, after, "controller.late") == 2.5  # made in the window
+    assert spans.delta(before, after, "channel.recv_transfers") is None
+    with pytest.raises(TypeError):
+        spans.delta(before, after, "channel.recv")
+
+
+def test_the_span_tool_keeps_its_keys_on_a_quantized_cell():
+    w = bench_tiny.workload(bench_tiny.pick(codec="int8"))
+    out = spans.run(w, 2**31 + 5, 0.2, require_chip=False)
+    assert list(out) == ["workload", "seed", "device", "rounds", "attempted", "failed",
+                         "round_s", "window_s", "busy_s", "metrics", "check", "spans",
+                         "idle", "breakdown"]
+    assert out["failed"] == 0 and out["attempted"] == 4 * out["rounds"] > 0
+    assert set(out["metrics"]) == set(spans.METRICS)
+    assert list(out["idle"]) == ["idle_s", "no_span_s", "threads", "by_role"]

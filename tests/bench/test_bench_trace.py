@@ -24,6 +24,27 @@ def test_idle_goes_to_the_innermost_open_span():
     assert charged == {"fit": 1 + 2, "upload": 1, trace.NO_SPAN: 2}
 
 
+def test_a_moment_is_charged_once_for_each_idle_device():
+    spans = [("upload", 0, 10)]
+    # Two devices idle over [2, 4), one of them also over [6, 8).
+    charged = trace.attribute([(2, 4), (6, 8), (2, 4)], spans)
+    assert charged == {"upload": 2 + 2 + 2}
+
+
+def test_reduce_averages_idle_over_the_devices_as_busy_time():
+    host = [(trace.WINDOW, 0.0, 10 * MS), ("bench.upload", 0.0, 10 * MS)]
+    planes = [
+        ("/host:CPU", [("python", host)]),
+        ("/device:TPU:0", [("XLA Modules", [("jit_step(1)", 0.0, 8 * MS)])]),
+        ("/device:TPU:1", [("XLA Modules", [("jit_gather(2)", 0.0, 2 * MS)])]),
+    ]
+    s = trace.reduce(planes)
+    assert s.devices == 2
+    assert s.busy_s == pytest.approx(0.005)
+    assert s.idle_by_span == pytest.approx({"upload": 0.005})
+    assert s.busy_s + sum(s.idle_by_span.values()) == pytest.approx(s.window_s)
+
+
 def _planes(device_events, host_events):
     return [
         ("/host:CPU", [("python", host_events)]),
